@@ -34,6 +34,7 @@ import sys
 import numpy as np
 
 from benchmarks import common
+from repro import compile_cache
 
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_chains.json")
 
@@ -244,4 +245,5 @@ def main(out_path: str = OUT_PATH, long: bool = False):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main(long="--long" in sys.argv)

@@ -11,6 +11,8 @@ import sys
 
 import numpy as np
 
+from repro import compile_cache
+
 from . import fidelity, roofline
 from .common import emit
 
@@ -55,4 +57,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.core import programs
 from repro.core.engine import ChainEngine
+from repro import compile_cache
 
 BATCHES = (1, 16, 64, 256)
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_chains.json")
@@ -176,4 +177,5 @@ def main(out_path: str = OUT_PATH):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -26,6 +26,8 @@ import json
 import os
 import sys
 
+from repro import compile_cache
+
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_chains.json")
 
 
@@ -124,4 +126,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     sys.exit(main())

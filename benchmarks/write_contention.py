@@ -33,6 +33,8 @@ import os
 
 import numpy as np
 
+from repro import compile_cache
+
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_chains.json")
 
 N_BUCKETS = 32
@@ -151,4 +153,5 @@ def main(out_path: str = OUT_PATH):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

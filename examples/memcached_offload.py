@@ -6,10 +6,8 @@ properties RedN buys: per-tenant isolation and host-crash survival.
 
 Run: PYTHONPATH=src python examples/memcached_offload.py
 """
-import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.data.pipeline import kv_request_stream
 from repro.kvstore import store as kv_store
@@ -24,8 +22,8 @@ def main():
     for k in range(1, n_keys + 1):
         if not kv.set(k, [k, k * 2, k * 3, k * 5]):
             raise RuntimeError(f"seeding key {k} needs a resize")
-    dk, dv = kv.device_arrays()
-    mesh = Mesh(np.array(jax.devices()[:1]), ("kv",))
+    mesh = kv_store.serving_mesh(kv.n_shards, "kv")
+    dk, dv = kv.device_arrays(NamedSharding(mesh, P("kv")))
 
     print("== serve 4 batches of 64 zipf gets via each path ==")
     stream = kv_request_stream(n_keys, 64, seed=1)

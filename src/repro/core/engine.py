@@ -20,8 +20,8 @@ key; this module is the batched front door that replaces that pattern:
   on-chain lap counters — but in a single device call with no host
   round-trips between requests.
 * **Pallas backend** — for single-WQ programs (the recycled get server's
-  lap loop, straight-line chains) ``backend="pallas"`` runs the batch as a
-  grid of client contexts through the widened managed-WQ kernel in
+  lap loop, straight-line chains) ``backend="pallas"`` runs the batch on
+  the TPU as a grid of client contexts through the managed-WQ kernel in
   :mod:`repro.kernels.chain_vm`, with the interpreter as oracle.
 
 Migration (single-request → batched)::
@@ -106,13 +106,13 @@ class ChainEngine:
     * ``"interp"`` (default) — the multi-WQ discrete-event interpreter in
       :mod:`repro.core.machine` (full ISA, latency clocks).
     * ``"pallas"`` — the single-WQ managed-chain Pallas kernel
-      (:mod:`repro.kernels.chain_vm`); compiles on TPU, falls back to
-      pallas interpret mode elsewhere.  Models memory, queue counters,
+      (:mod:`repro.kernels.chain_vm`), compiled for the TPU; constructing
+      it on any other backend raises.  Models memory, queue counters,
       steps, and client responses, but not the latency cost model: the
       ``clock``/``last_comp_time`` fields and the ``verb_counts``
       histogram are passed through unchanged.
-    * ``"pallas-interpret"`` — force pallas interpret mode (CPU oracle
-      checks).
+    * ``"pallas-interpret"`` — the same kernel in pallas interpret mode
+      (CPU oracle checks).
     """
 
     # Bounded LRU of engines keyed (spec, backend).  Evicting an engine
@@ -133,6 +133,11 @@ class ChainEngine:
             raise ValueError(
                 "pallas backend supports single-WQ programs only "
                 f"(spec has {spec.num_wqs} WQs)")
+        if backend == "pallas" and jax.default_backend() != "tpu":
+            raise ValueError(
+                "backend='pallas' compiles the chain-VM kernel for the TPU, "
+                f"but JAX's default backend is {jax.default_backend()!r}; "
+                "use 'pallas-interpret' to run the kernel in interpret mode")
         self.spec = spec
         self.backend = backend
         # pallas-subset validation, keyed on the code-region image: engines
@@ -344,8 +349,7 @@ class ChainEngine:
              states.msg_head[:, 0], states.msg_tail[:, 0],
              fuel.astype(jnp.int32),
              states.halted.astype(jnp.int32)], axis=1)
-        impl = ("interpret" if self.backend == "pallas-interpret"
-                or jax.default_backend() != "tpu" else "pallas")
+        impl = "interpret" if self.backend == "pallas-interpret" else "pallas"
         mem, stats = chain_ops.run_managed(
             states.mem, msgs, inits, wq_base=spec.wq_bases[0],
             n_wrs=spec.wq_sizes[0], managed=bool(spec.managed[0]),

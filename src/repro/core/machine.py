@@ -86,31 +86,30 @@ def init_state(spec: MachineSpec, mem_image: np.ndarray,
                tails: Sequence[int], enable_limits: Sequence[int]) -> VMState:
     mem = np.zeros(spec.mem_words + GUARD_WORDS, dtype=np.int32)
     mem[: len(mem_image)] = mem_image
-    # the image is pure host data; force concrete arrays even when a
-    # (cached) program builder is first reached inside a jit trace —
-    # otherwise the cache would retain dead tracers
-    with jax.ensure_compile_time_eval():
-        return _init_state_arrays(spec, mem, tails, enable_limits)
-
-
-def _init_state_arrays(spec, mem, tails, enable_limits) -> VMState:
     n = spec.num_wqs
-    return VMState(
-        mem=jnp.asarray(mem),
-        head=jnp.zeros(n, jnp.int32),
-        tail=jnp.asarray(np.asarray(tails, np.int32)),
-        enable_limit=jnp.asarray(np.asarray(enable_limits, np.int32)),
-        completions=jnp.zeros(n, jnp.int32),
-        last_comp_time=jnp.zeros(n, jnp.float32),
-        msg_buf=jnp.zeros((n, spec.msg_capacity, isa.MSG_WORDS), jnp.int32),
-        msg_head=jnp.zeros(n, jnp.int32),
-        msg_tail=jnp.zeros(n, jnp.int32),
-        clock=jnp.zeros(n, jnp.float32),
-        steps=jnp.zeros((), jnp.int32),
-        halted=jnp.zeros((), jnp.bool_),
-        verb_counts=jnp.zeros(isa.NUM_OPCODES, jnp.int32),
-        responses=jnp.zeros((), jnp.int32),
+    host = VMState(
+        mem=mem,
+        head=np.zeros(n, np.int32),
+        tail=np.asarray(tails, np.int32),
+        enable_limit=np.asarray(enable_limits, np.int32),
+        completions=np.zeros(n, np.int32),
+        last_comp_time=np.zeros(n, np.float32),
+        msg_buf=np.zeros((n, spec.msg_capacity, isa.MSG_WORDS), np.int32),
+        msg_head=np.zeros(n, np.int32),
+        msg_tail=np.zeros(n, np.int32),
+        clock=np.zeros(n, np.float32),
+        steps=np.zeros((), np.int32),
+        halted=np.zeros((), np.bool_),
+        verb_counts=np.zeros(isa.NUM_OPCODES, np.int32),
+        responses=np.zeros((), np.int32),
     )
+    # the image is pure host data: every leaf is a concrete array made
+    # from numpy, even when a (cached) program builder is first reached
+    # inside a trace.  A creation op such as ``jnp.zeros`` there would be
+    # stamped with the trace's mesh, and the cached state would then be
+    # refused inside a shard_map over a mesh of another size.
+    with jax.ensure_compile_time_eval():
+        return jax.tree_util.tree_map(jnp.asarray, host)
 
 
 # ---------------------------------------------------------------------------

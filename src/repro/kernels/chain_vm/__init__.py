@@ -1,2 +1,1 @@
-from .ops import run_chains  # noqa: F401
-from .ref import run_chain_reference  # noqa: F401
+from .ops import run_managed  # noqa: F401

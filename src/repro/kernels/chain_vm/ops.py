@@ -2,44 +2,28 @@
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
-import jax.numpy as jnp
 
-from .kernel import run_chains_pallas, run_managed_pallas
-from .ref import managed_chain_loop, run_chain_reference
+from .kernel import run_managed_pallas
+from .ref import managed_chain_loop
 
-
-@functools.partial(jax.jit, static_argnames=("wq_base", "n_wrs",
-                                             "max_steps", "impl"))
-def run_chains(mems, *, wq_base: int, n_wrs: int, max_steps: int = 64,
-               impl: Optional[str] = None):
-    """Execute one single-WQ chain per row of ``mems`` (n_clients, M)."""
-    if impl is None:
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
-    if impl == "ref":
-        out, _ = jax.vmap(
-            lambda m: run_chain_reference(m, wq_base, n_wrs, max_steps))(mems)
-        return out
-    return run_chains_pallas(mems, wq_base=wq_base, n_wrs=n_wrs,
-                             max_steps=max_steps,
-                             interpret=(impl == "interpret"))
+IMPLS = ("pallas", "interpret", "ref")
 
 
 @functools.partial(jax.jit, static_argnames=("wq_base", "n_wrs", "managed",
                                              "max_steps", "impl"))
 def run_managed(mems, msgs, inits, *, wq_base: int, n_wrs: int,
-                managed: bool = True, max_steps: int = 64,
-                impl: Optional[str] = None):
+                managed: bool = True, max_steps: int = 64, impl: str):
     """Managed-WQ batch executor (ENABLE gate + completions + RECV).
 
     One client context per row; see :func:`kernel.run_managed_pallas` for
-    the input layout.  ``impl``: "pallas" (TPU), "interpret" (pallas
-    interpret mode), or "ref" (vmapped pure-jnp oracle).
+    the input layout.  ``impl``: "pallas" (compiled TPU kernel),
+    "interpret" (the same kernel in pallas interpret mode), or "ref" (the
+    vmapped pure-jnp oracle).
     """
-    if impl is None:
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    if impl not in IMPLS:
+        raise ValueError(f"impl must be one of {IMPLS}, got {impl!r}")
     if impl == "ref":
         return jax.vmap(
             lambda m, g, i: managed_chain_loop(

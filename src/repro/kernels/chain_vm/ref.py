@@ -1,20 +1,16 @@
 """Oracle for the single-WQ chain executor: a pure-jnp in-order interpreter
 over the same 8-word WR ISA as repro.core.
 
-Two tiers:
-
-* :func:`step_wr` / :func:`run_chain_reference` — the original straight-line
-  subset (no WAIT/ENABLE/SEND/RECV): a single queue is totally ordered and
-  triggers are applied by scattering the request into memory up front.
-* :func:`step_wr_managed` / :func:`managed_chain_loop` — the managed-WQ
-  semantics the recycled get server needs: an ENABLE-gated head limit,
-  completion counters (WAIT-on-self), RECV consuming messages from a staged
-  per-context message region, client-response SEND, and CAS/ADD return-old.
-  A blocked head WR (unsatisfied WAIT, empty message queue, head at the
-  enable limit) quiesces the context — on a single queue nothing else can
-  unblock it.  The same loop body runs inside the Pallas kernel
-  (``kernel.run_managed_pallas``), so the interpreter here is its bit-exact
-  oracle.
+:func:`step_wr_managed` / :func:`managed_chain_loop` give the managed-WQ
+semantics the recycled get server needs: an ENABLE-gated head limit,
+completion counters (WAIT-on-self), RECV consuming messages from a staged
+per-context message region, client-response SEND, and CAS/ADD return-old.
+A blocked head WR (unsatisfied WAIT, empty message queue, head at the
+enable limit) quiesces the context — on a single queue nothing else can
+unblock it.  A plain (unmanaged) WQ is the same loop without the ENABLE
+gate.  The Pallas kernel (``kernel.run_managed_pallas``) implements the
+same semantics over an SMEM image, and this interpreter is its bit-exact
+oracle.
 """
 from __future__ import annotations
 
@@ -37,45 +33,6 @@ def _copy(mem, src, dst, ln):
     cur = lax.dynamic_slice(mem, (dst,), (isa.MAX_COPY,))
     out = jnp.where(jnp.arange(isa.MAX_COPY) < ln, blk, cur)
     return lax.dynamic_update_slice(mem, out, (dst,))
-
-
-def step_wr(mem, wr_addr):
-    """Execute the WR at wr_addr; returns (mem, halted)."""
-    ctrl = mem[wr_addr + isa.F_CTRL]
-    opcode = jnp.clip((ctrl >> isa.ID_BITS) & 0x7F, 0, isa.NUM_OPCODES - 1)
-    src = mem[wr_addr + isa.F_SRC]
-    dst = mem[wr_addr + isa.F_DST]
-    ln = mem[wr_addr + isa.F_LEN]
-    opa = mem[wr_addr + isa.F_OPA]
-    opb = mem[wr_addr + isa.F_OPB]
-    d = jnp.maximum(dst, 0)
-
-    def noop(m):
-        return m
-
-    def write(m):
-        return _copy(m, src, d, ln)
-
-    def write_imm(m):
-        return m.at[d].set(opa)
-
-    def cas(m):
-        old = m[d]
-        return m.at[d].set(jnp.where(old == opa, opb, old))
-
-    def add(m):
-        return m.at[d].add(opa)
-
-    def max_(m):
-        return m.at[d].max(opa)
-
-    def min_(m):
-        return m.at[d].min(opa)
-
-    branches = [noop, write, write_imm, write, noop, noop, cas, add,
-                max_, min_, noop, noop, noop]
-    mem = lax.switch(opcode, branches, mem)
-    return mem, opcode == isa.HALT
 
 
 # the atomic return-old store is shared with the core machine so the
@@ -208,20 +165,3 @@ def managed_chain_loop(mem, msgs, init, *, wq_base: int, n_wrs: int,
         head, enable, comps, mhead, halted.astype(jnp.int32),
         stopped.astype(jnp.int32), resps, jnp.zeros((), jnp.int32)])
     return mem, stats
-
-
-def run_chain_reference(mem, wq_base: int, n_wrs: int, max_steps: int):
-    """Run up to max_steps WRs of a single circular WQ starting at slot 0."""
-
-    def body(carry, _):
-        m, head, halted = carry
-        addr = wq_base + (head % n_wrs) * isa.WR_WORDS
-        m2, h2 = step_wr(m, addr)
-        m = jnp.where(halted, m, m2)        # frozen once halted
-        head = head + jnp.where(halted, 0, 1)
-        return (m, head, halted | h2), None
-
-    (mem, head, halted), _ = lax.scan(
-        body, (mem, jnp.zeros((), jnp.int32), jnp.zeros((), jnp.bool_)),
-        None, length=max_steps)
-    return mem, head

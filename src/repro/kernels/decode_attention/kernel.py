@@ -14,9 +14,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from ... import compat
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -109,7 +107,7 @@ def decode_partial_pallas(q, k, v, lengths, *, window: int = 0,
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, d), jnp.float32),
         ],
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths, q, k, v)

@@ -21,9 +21,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from ... import compat
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -137,7 +135,7 @@ def flash_attention_pallas(q, k, v, *, mode: str = "causal", window: int = 0,
             pltpu.VMEM((bq, 1), jnp.float32),     # running sum
             pltpu.VMEM((bq, d), jnp.float32),     # output accumulator
         ],
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

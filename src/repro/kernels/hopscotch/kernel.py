@@ -21,9 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from ... import compat
+from jax.experimental.pallas import tpu as pltpu
 
 _MULT = 2654435761
 
@@ -94,7 +92,7 @@ def hopscotch_lookup_pallas(keys, values, queries, neighborhood: int, *,
             pltpu.VMEM((bq, v), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(queries, keys, values)

@@ -15,9 +15,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from ... import compat
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _chunk_math(a, u, h0):
@@ -69,7 +67,7 @@ def rglru_pallas(a, u, *, chunk: int = 32, interpret: bool = False):
             jax.ShapeDtypeStruct((b, d), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(a, u)

@@ -22,9 +22,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from ... import compat
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _chunk_math(r, k, v, w, u, S0):
@@ -109,7 +107,7 @@ def wkv6_pallas(r, k, v, w, u, *, chunk: int = 32, interpret: bool = False):
             jax.ShapeDtypeStruct((b, h, n, m), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, m), jnp.float32)],
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, w, u)

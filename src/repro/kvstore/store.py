@@ -76,7 +76,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map
 from ..core import faults as faults_mod
 from ..core import machine
 from ..core import programs
@@ -190,6 +189,18 @@ class GetResult(NamedTuple):
                 f"deferred={int(np.asarray(self.deferred).sum())})")
 
 
+def serving_mesh(n_shards: int, axis: str = "kv") -> Mesh:
+    """A 1-D mesh with one device per shard.  Raises when JAX sees fewer
+    devices than shards: a smaller mesh would silently serve every shard
+    from fewer chips than the deployment asked for."""
+    devices = jax.devices()
+    if len(devices) < n_shards:
+        raise ValueError(
+            f"n_shards={n_shards} needs one device per shard, but JAX sees "
+            f"{len(devices)} {devices[0].platform} device(s)")
+    return Mesh(np.array(devices[:n_shards]), (axis,))
+
+
 @dataclasses.dataclass
 class ShardedKV:
     """Host handle: per-shard hopscotch tables + device arrays."""
@@ -222,10 +233,13 @@ class ShardedKV:
         return self.tables[int(shard_of(key, self.n_shards))].insert(
             key, value)
 
-    def device_arrays(self) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        keys = jnp.stack([jnp.asarray(t.keys) for t in self.tables])
-        vals = jnp.stack([jnp.asarray(t.values) for t in self.tables])
-        return keys, vals     # (S, B), (S, B, V)
+    def device_arrays(self, sharding=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """The tables as ``(S, B)`` keys and ``(S, B, V)`` values, placed
+        with ``sharding`` (one shard per device of a serving mesh) or on
+        the default device."""
+        keys = np.stack([t.keys for t in self.tables])
+        vals = np.stack([t.values for t in self.tables])
+        return jax.device_put(keys, sharding), jax.device_put(vals, sharding)
 
     def sync_from_device(self, keys, vals):
         """Refresh the host tables *from* the authoritative device arrays
@@ -506,8 +520,10 @@ def mapped_cache_stats() -> dict:
 
 
 def _mesh_fingerprint(mesh: Mesh):
+    # device ids repeat across platforms (a described TPU topology and the
+    # host CPU both have a device 0), so the platform is part of the key
     return (tuple(mesh.axis_names), tuple(mesh.devices.shape),
-            tuple(d.id for d in mesh.devices.flat))
+            tuple((d.platform, d.id) for d in mesh.devices.flat))
 
 
 def _mapped_get(mesh: Mesh, axis: str, method: str, n_shards: int,
@@ -534,7 +550,7 @@ def _mapped_get(mesh: Mesh, axis: str, method: str, n_shards: int,
         return found, v, ok, dropped, deferred
 
     spec = P(axis)
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec, spec),
         out_specs=(spec, spec, spec, spec, spec), check_vma=False))
     return _mapped_cache_put(key, fn)
@@ -562,7 +578,7 @@ def _mapped_get_ttl(mesh: Mesh, axis: str, n_shards: int, capacity: int,
         return found, v, ok, dropped, deferred
 
     spec = P(axis)
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(spec,) * 6, out_specs=(spec,) * 5,
         check_vma=False))
     return _mapped_cache_put(key, fn)
@@ -1127,7 +1143,7 @@ def _mapped_set(mesh: Mesh, axis: str, n_shards: int, capacity: int,
         n_in = 5
 
     spec = P(axis)
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(spec,) * n_in, out_specs=(spec,) * 6,
         check_vma=False))
     return _mapped_cache_put(key, fn)
@@ -1229,7 +1245,7 @@ def _mapped_del(mesh: Mesh, axis: str, n_shards: int, capacity: int,
         return status, ok, dropped, deferred, nk, nv
 
     spec = P(axis)
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(spec,) * 4, out_specs=(spec,) * 6,
         check_vma=False))
     return _mapped_cache_put(key, fn)
@@ -1305,7 +1321,7 @@ def _mapped_sweep(mesh: Mesh, axis: str, count: int, val_words: int):
     body = functools.partial(_sweep_local, count=count,
                              val_words=val_words)
     spec = P(axis)
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(spec,) * 5, out_specs=(spec,) * 6,
         check_vma=False))
     return _mapped_cache_put(key, fn)
@@ -1584,7 +1600,7 @@ def _mapped_resize(mesh: Mesh, axis: str, step: int, neighborhood: int,
         body = functools.partial(_resize_local, **kw)
         n_in = 5
     spec = P(axis)
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(spec,) * n_in, out_specs=(spec,) * 9,
         check_vma=False))
     return _mapped_cache_put(key, fn)
@@ -1703,7 +1719,7 @@ def _mapped_mig_get(mesh: Mesh, axis: str, n_shards: int, capacity: int,
         return found, v, okk, dropped, deferred
 
     spec = P(axis)
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(spec,) * 7, out_specs=(spec,) * 5,
         check_vma=False))
     return _mapped_cache_put(key, fn)
@@ -1866,7 +1882,7 @@ def _mapped_mig_set(mesh: Mesh, axis: str, n_shards: int, capacity: int,
         return status, okf, dropped, deferred, tk, tv, gk, gv
 
     spec = P(axis)
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(spec,) * 8, out_specs=(spec,) * 8,
         check_vma=False))
     return _mapped_cache_put(key, fn)
@@ -1904,13 +1920,18 @@ def repair_bucket(keys: jnp.ndarray, vals: jnp.ndarray, shard: int,
 # ---------------------------------------------------------------------------
 
 def reference_get(kv: ShardedKV, queries: np.ndarray):
-    out = np.zeros((len(queries), kv.val_words), np.int32)
-    found = np.zeros(len(queries), bool)
-    for i, q in enumerate(np.asarray(queries).tolist()):
-        t = kv.tables[int(shard_of(q, kv.n_shards))]
-        f, v = hopscotch.lookup(*t.as_device(),
-                                jnp.asarray([q], jnp.int32),
-                                kv.neighborhood)
-        found[i] = bool(f[0])
-        out[i] = np.asarray(v[0])
+    """Oracle gets against the host tables: each query is looked up in
+    its owner shard's :class:`repro.kvstore.hopscotch.HopscotchTable` by
+    :func:`repro.kvstore.hopscotch.lookup`, one batched lookup per owner.
+    Returns ``(found (B,), values (B, V))``."""
+    q = np.asarray(queries, np.int32).reshape(-1)
+    out = np.zeros((len(q), kv.val_words), np.int32)
+    found = np.zeros(len(q), bool)
+    owner = np.asarray(shard_of(jnp.asarray(q), kv.n_shards))
+    for s in np.unique(owner).tolist():
+        idx = np.flatnonzero(owner == s)
+        f, v = hopscotch.lookup(*kv.tables[s].as_device(),
+                                jnp.asarray(q[idx]), kv.neighborhood)
+        found[idx] = np.asarray(f)
+        out[idx] = np.asarray(v)
     return found, out

@@ -166,9 +166,10 @@ class ShardedKVService(_HostDriverLifecycle):
               val_words: int = 2, axis: str = "kv",
               ttl: bool = False) -> "ShardedKVService":
         import jax
-        import jax.numpy as jnp
-        from jax.sharding import Mesh
+        from jax.sharding import NamedSharding, PartitionSpec
 
+        mesh = kv_store.serving_mesh(n_shards, axis)
+        sharding = NamedSharding(mesh, PartitionSpec(axis))
         kv = kv_store.ShardedKV.build(n_shards, buckets_per_shard, val_words)
         for k, v in items:
             if not kv.set(int(k), list(v)):
@@ -179,16 +180,17 @@ class ShardedKVService(_HostDriverLifecycle):
                     f"bootstrap insert of key {int(k)} needs a resize "
                     f"(buckets_per_shard={buckets_per_shard} too tight "
                     "for this item set)")
-        keys, vals = kv.device_arrays()
-        mesh = Mesh(np.array(jax.devices()[:n_shards]), (axis,))
+        keys, vals = kv.device_arrays(sharding)
         svc = cls(kv=kv, mesh=mesh, axis=axis, keys=keys, vals=vals,
                   driver=HostDriver())
         if ttl:
             # bootstrap items carry no TTL; deadlines arrive via
             # set_many(..., deadlines=...) and are served/evicted by the
             # TTL get server and the CLOCK sweeper chains
-            svc.exp = jnp.full(keys.shape, programs.NO_TTL, jnp.int32)
-            svc.sweep_hand = jnp.zeros((keys.shape[0],), jnp.int32)
+            svc.exp = jax.device_put(
+                np.full(keys.shape, programs.NO_TTL, np.int32), sharding)
+            svc.sweep_hand = jax.device_put(
+                np.zeros((n_shards,), np.int32), sharding)
         return svc
 
     # -- the serving path (pure device state) --------------------------------

@@ -40,10 +40,9 @@ def run_pipe(w_all, x):
         out = jax.lax.psum(out, "pod") - (S - 1) * 0.0
         return out
 
-    from repro.compat import shard_map
-    f = shard_map(body, mesh=mesh,
-                  in_specs=(P("pod"), P()), out_specs=P(),
-                  check_vma=False)
+    f = jax.shard_map(body, mesh=mesh,
+                      in_specs=(P("pod"), P()), out_specs=P(),
+                      check_vma=False)
     return f(w_all, x)
 
 

@@ -1,12 +1,13 @@
-"""Property test: the Pallas chain_vm executor and the core multi-WQ
+"""Property test: the Pallas chain_vm kernel and the core multi-WQ
 machine agree on random single-WQ straight-line programs — the kernel
 really is a NIC PU running the same ISA."""
+import jax
 import jax.numpy as jnp
 import numpy as np
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
-from repro.core import assembler, isa, machine
-from repro.kernels.chain_vm import ops as chain_ops
+from repro.core import assembler, machine
+from repro.core.engine import ChainEngine
 
 
 @settings(max_examples=20, deadline=None)
@@ -42,12 +43,11 @@ def test_chain_vm_matches_core_machine_on_random_programs(data):
 
     spec, st0 = p.finalize()
     out_core = machine.run(spec, st0, max_steps=n_wrs + 2)
-    # keep the MAX_COPY guard words: copy verbs near the end of memory
-    # clamp differently without them
-    mem0 = np.asarray(st0.mem)
-    out_kern = chain_ops.run_chains(
-        jnp.asarray(mem0[None]), wq_base=spec.wq_bases[0],
-        n_wrs=spec.wq_sizes[0], max_steps=n_wrs + 2, impl="ref")
-    core_mem = np.asarray(out_core.mem)
-    kern_mem = np.asarray(out_kern[0])
-    np.testing.assert_array_equal(core_mem, kern_mem)
+    # the image keeps its MAX_COPY guard words: copy verbs near the end of
+    # memory clamp differently without them
+    batch = jax.tree_util.tree_map(lambda a: jnp.stack([a]), st0)
+    out_kern = ChainEngine.for_spec(spec, "pallas-interpret").run_batch(
+        batch, n_wrs + 2)
+    np.testing.assert_array_equal(np.asarray(out_core.mem),
+                                  np.asarray(out_kern.mem[0]))
+    assert int(out_kern.steps[0]) == int(out_core.steps)

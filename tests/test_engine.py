@@ -249,6 +249,15 @@ def test_pallas_backend_rejects_inter_qp_send():
         eng.run_batch(batch, 8)
 
 
+def test_pallas_backend_raises_off_tpu():
+    """backend='pallas' is the compiled TPU kernel: on any other backend it
+    raises instead of quietly running the kernel in interpret mode."""
+    assert jax.default_backend() != "tpu"
+    srv = programs.build_recycled_get_server(n_buckets=8, val_len=2)
+    with pytest.raises(ValueError, match="pallas-interpret"):
+        ChainEngine(srv.spec, backend="pallas")
+
+
 def test_pallas_backend_rejects_multi_wq_specs():
     off = programs.build_hash_lookup(n_buckets=16, val_len=2)
     with pytest.raises(ValueError):

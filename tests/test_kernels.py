@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import isa
 from repro.kernels.chain_vm import ops as chain_ops
@@ -221,7 +221,7 @@ def test_hopscotch_kernel_property(nkeys, seed):
 # --- chain_vm -------------------------------------------------------------------------
 
 def _build_toy_chain():
-    """A small self-modifying chain as a raw memory image."""
+    """A small self-modifying chain on one plain (unmanaged) WQ."""
     from repro.core import assembler
     p = assembler.Program(256)
     x = p.word(5)
@@ -238,27 +238,39 @@ def _build_toy_chain():
     wq.cas(dst=y, old=42, new=43)
     wq.halt()
     spec, state = p.finalize()
-    return np.asarray(state.mem), spec.wq_bases[0], 8, dict(
+    return np.asarray(state.mem), spec, int(state.tail[0]), dict(
         x=x, y=y, flag=flag)
 
 
+def _run_plain_wq(mems, spec, tail, impl, max_steps=8):
+    """Run one plain-WQ context per row of ``mems`` through the chain-VM
+    batch executor (no staged messages, ``max_steps`` WRs of fuel)."""
+    n = mems.shape[0]
+    msgs = jnp.zeros((n, spec.msg_capacity * isa.MSG_WORDS), jnp.int32)
+    inits = np.zeros((n, 8), np.int32)
+    inits[:, 1] = tail                     # INIT_TAIL: posted WRs
+    inits[:, 6] = max_steps                # INIT_FUEL
+    mem, _ = chain_ops.run_managed(
+        mems, msgs, jnp.asarray(inits), wq_base=spec.wq_bases[0],
+        n_wrs=spec.wq_sizes[0], managed=False, max_steps=max_steps,
+        impl=impl)
+    return np.asarray(mem)
+
+
 def test_chain_vm_matches_core_semantics():
-    mem, base, n_wrs, addrs = _build_toy_chain()
+    mem, spec, tail, addrs = _build_toy_chain()
     batch = jnp.asarray(np.stack([mem] * 4))
     for impl in ("ref", "interpret"):
-        out = chain_ops.run_chains(batch, wq_base=base, n_wrs=n_wrs,
-                                   max_steps=8, impl=impl)
-        got = np.asarray(out)
+        got = _run_plain_wq(batch, spec, tail, impl)
         assert (got[:, addrs["y"]] == 43).all(), impl
         assert (got[:, addrs["flag"]] == 99).all(), impl
 
 
 def test_chain_vm_batch_independence():
-    mem, base, n_wrs, addrs = _build_toy_chain()
+    mem, spec, tail, addrs = _build_toy_chain()
     m2 = mem.copy()
     m2[addrs["x"]] = 100                    # different input for client 1
     batch = jnp.asarray(np.stack([mem, m2]))
-    out = np.asarray(chain_ops.run_chains(batch, wq_base=base, n_wrs=n_wrs,
-                                          max_steps=8, impl="interpret"))
+    out = _run_plain_wq(batch, spec, tail, "interpret")
     assert out[0, addrs["y"]] == 43
     assert out[1, addrs["y"]] == 137        # 100 + 37, CAS(42) failed

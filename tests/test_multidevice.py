@@ -40,3 +40,9 @@ def test_gpipe_pipeline_4stage():
 def test_elastic_remesh_restore():
     r = run_sub("elastic_main.py")
     assert "ELASTIC_OK" in r.stdout, r.stdout + r.stderr
+
+
+@pytest.mark.slow
+def test_chip_smoke_four_shard_phase_4dev():
+    r = run_sub("chip_smoke_main.py", n_devices=4)
+    assert "CHIP_SMOKE_4DEV_OK" in r.stdout, r.stdout + r.stderr

@@ -1,7 +1,7 @@
 """Tests for the paper's offload programs (Figs. 3, 9, 12; §3.4 recycling)."""
 import numpy as np
 import pytest
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import isa, machine, programs
 

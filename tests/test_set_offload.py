@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import programs
 from repro.core.engine import ChainEngine
@@ -278,3 +278,17 @@ def test_service_set_many_batched(mesh_kv):
     r = svc.get_many(sk)
     assert np.asarray(r.found[0]).all()
     np.testing.assert_array_equal(np.asarray(r.values[0]), sv)
+
+
+def test_service_start_needs_one_device_per_shard():
+    """Two shards on a one-device host must not quietly share a chip."""
+    assert len(jax.devices()) == 1
+    with pytest.raises(ValueError, match="one device per shard"):
+        failure.ShardedKVService.start([(5, [1, 2])], n_shards=2)
+
+
+def test_service_state_is_placed_on_its_mesh():
+    svc = failure.ShardedKVService.start([(5, [1, 2])], ttl=True)
+    want = NamedSharding(svc.mesh, P(svc.axis))
+    for arr in (svc.keys, svc.vals, svc.exp, svc.sweep_hand):
+        assert arr.sharding.is_equivalent_to(want, arr.ndim)

@@ -122,11 +122,10 @@ def test_straggler_masked_combine():
             {"w": g["w"][0]}, alive[0], "data")
         return out["w"][None], n_live[None]
 
-    from repro.compat import shard_map
-    f = shard_map(body, mesh=mesh,
-                  in_specs=(jax.sharding.PartitionSpec("data"),) * 2,
-                  out_specs=(jax.sharding.PartitionSpec("data"),) * 2,
-                  check_vma=False)
+    f = jax.shard_map(body, mesh=mesh,
+                      in_specs=(jax.sharding.PartitionSpec("data"),) * 2,
+                      out_specs=(jax.sharding.PartitionSpec("data"),) * 2,
+                      check_vma=False)
     out, n = f(grads, jnp.asarray([True]))
     assert float(n[0]) == 1.0
     np.testing.assert_array_equal(np.asarray(out[0]), np.ones(4))

@@ -2,7 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.rdma import transport
 
@@ -100,11 +100,10 @@ def test_dispatch_combine_roundtrip_identity(data):
         out = transport.combine(resp.reshape(1, cap, -1), d, pos, ok, "kv")
         return out, ok
 
-    from repro.compat import shard_map
-    f = shard_map(body, mesh=mesh,
-                  in_specs=(jax.sharding.PartitionSpec(),) * 2,
-                  out_specs=(jax.sharding.PartitionSpec(),) * 2,
-                  check_vma=False)
+    f = jax.shard_map(body, mesh=mesh,
+                      in_specs=(jax.sharding.PartitionSpec(),) * 2,
+                      out_specs=(jax.sharding.PartitionSpec(),) * 2,
+                      check_vma=False)
     out, ok = f(payload, dest)
     out = np.asarray(out)[:, 0]
     ok = np.asarray(ok)
@@ -120,7 +119,6 @@ def test_dispatch_live_mask_frees_slots():
     first half of a same-destination batch deferred, the second half all
     fits in a half-sized capacity window."""
     from jax.sharding import Mesh
-    from repro.compat import shard_map
     mesh = Mesh(np.array(jax.devices()[:1]), ("kv",))
     n, cap = 8, 4
     payload = jnp.arange(1, n + 1, dtype=jnp.int32)[:, None]
@@ -132,10 +130,10 @@ def test_dispatch_live_mask_frees_slots():
         out = transport.combine(recv.reshape(1, cap, -1), d, pos, ok, "kv")
         return out, ok
 
-    f = shard_map(body, mesh=mesh,
-                  in_specs=(jax.sharding.PartitionSpec(),) * 3,
-                  out_specs=(jax.sharding.PartitionSpec(),) * 2,
-                  check_vma=False)
+    f = jax.shard_map(body, mesh=mesh,
+                      in_specs=(jax.sharding.PartitionSpec(),) * 3,
+                      out_specs=(jax.sharding.PartitionSpec(),) * 2,
+                      check_vma=False)
     out, ok = f(payload, dest, live)
     assert not np.asarray(ok)[:4].any()
     assert np.asarray(ok)[4:].all()
@@ -148,7 +146,6 @@ def test_triggered_chain_stateful_serializes_and_threads_carry():
     stateful step — each request observes every earlier one's writes, and
     over-capacity rows are dropped (ok=False) without touching state."""
     from jax.sharding import Mesh
-    from repro.compat import shard_map
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("kv",))
     n, cap = 6, 4
@@ -167,8 +164,8 @@ def test_triggered_chain_stateful_serializes_and_threads_carry():
         return resp, ok, carry[None]
 
     spec = jax.sharding.PartitionSpec()
-    f = shard_map(body, mesh=mesh, in_specs=(spec, spec),
-                  out_specs=(spec, spec, spec), check_vma=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec),
+                      out_specs=(spec, spec, spec), check_vma=False)
     resp, ok, carry = f(payload, dest)
     assert np.asarray(ok)[:cap].all() and not np.asarray(ok)[cap:].any()
     # prefix sums prove sequential execution over the shared carry

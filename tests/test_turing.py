@@ -2,7 +2,7 @@
 from RDMA verbs (Appendix A, constructive form) runs real guest programs."""
 import numpy as np
 import pytest
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import machine, turing
 
