@@ -76,6 +76,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from .. import obs
 from ..core import faults as faults_mod
 from ..core import machine
 from ..core import programs
@@ -168,12 +169,16 @@ def _check_key_batch(arr, *, what: str, allow_zero: bool, live=None):
 class GetResult(NamedTuple):
     """Distributed get outcome. ``found``/``values`` are authoritative only
     where ``ok`` is True — a False row was dropped (capacity) or deferred
-    (admission), *not* a miss."""
+    (admission), *not* a miss.  ``vm_steps`` (steady-state chain paths
+    only) counts the WRs each chain-VM context of the owner's receive
+    window executed, padded slots included; the vmapped VM loop runs as
+    many trips as an owner's largest count."""
     found: jnp.ndarray      # (S, B) bool
     values: jnp.ndarray     # (S, B, V) int32
     ok: jnp.ndarray         # (S, B) bool — response authoritative
     dropped: jnp.ndarray    # (S,) int32 — capacity drops at the source
     deferred: jnp.ndarray   # (S,) int32 — admission-deferred at the source
+    vm_steps: Optional[jnp.ndarray] = None  # (S, S * capacity) int32
 
     def __repr__(self):
         # summarized, not the raw-array tuple dump — results show up in
@@ -265,10 +270,10 @@ def _redn_get_local(keys, vals, queries, live, *, n_shards, capacity, axis,
     srv = programs.build_hopscotch_server(n_buckets, val_words, neighborhood)
     state = srv.device_state(keys[0], vals[0])
     payload = srv.device_payloads(q, hopscotch.bucket_of(q, n_buckets))
-    resp, ok = transport.triggered_chain_engine(
+    resp, ok, steps = transport.triggered_chain_engine(
         srv.engine, state, srv.recv_wq, srv.resp_region, srv.resp_words,
         payload, dest, n_shards, capacity, axis, live.reshape(-1))
-    return (resp[:, 0] > 0)[None], resp[None, :, 1:], ok[None]
+    return (resp[:, 0] > 0)[None], resp[None, :, 1:], ok[None], steps[None]
 
 
 def _redn_get_ttl_local(keys, vals, exp, now, queries, live, *, n_shards,
@@ -286,10 +291,10 @@ def _redn_get_ttl_local(keys, vals, exp, now, queries, live, *, n_shards,
     state = srv.device_state(keys[0], vals[0], exp[0])
     payload = srv.device_payloads(q, hopscotch.bucket_of(q, n_buckets),
                                   now[0])
-    resp, ok = transport.triggered_chain_engine(
+    resp, ok, steps = transport.triggered_chain_engine(
         srv.engine, state, srv.recv_wq, srv.resp_region, srv.resp_words,
         payload, dest, n_shards, capacity, axis, live.reshape(-1))
-    return (resp[:, 0] > 0)[None], resp[None, :, 1:], ok[None]
+    return (resp[:, 0] > 0)[None], resp[None, :, 1:], ok[None], steps[None]
 
 
 def _one_sided_get_local(keys, vals, queries, live, *, n_shards, capacity,
@@ -543,16 +548,18 @@ def _mapped_get(mesh: Mesh, axis: str, method: str, n_shards: int,
         neighborhood=neighborhood, val_words=val_words)
 
     def body(keys, vals, queries, live):
-        found, v, ok = path(keys, vals, queries, live)
+        # the chain path also returns its contexts' VM steps
+        found, v, ok, *steps = path(keys, vals, queries, live)
         deferred = jnp.sum(~live, dtype=jnp.int32).reshape(1)
         dropped = (jnp.sum(live, dtype=jnp.int32)
                    - jnp.sum(ok, dtype=jnp.int32)).reshape(1)
-        return found, v, ok, dropped, deferred
+        return (found, v, ok, dropped, deferred, *steps)
 
     spec = P(axis)
+    n_out = 6 if method == "redn" else 5
     fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec, spec),
-        out_specs=(spec, spec, spec, spec, spec), check_vma=False))
+        out_specs=(spec,) * n_out, check_vma=False))
     return _mapped_cache_put(key, fn)
 
 
@@ -571,15 +578,15 @@ def _mapped_get_ttl(mesh: Mesh, axis: str, n_shards: int, capacity: int,
         axis=axis, neighborhood=neighborhood, val_words=val_words)
 
     def body(keys, vals, exp, nows, queries, live):
-        found, v, ok = path(keys, vals, exp, nows, queries, live)
+        found, v, ok, steps = path(keys, vals, exp, nows, queries, live)
         deferred = jnp.sum(~live, dtype=jnp.int32).reshape(1)
         dropped = (jnp.sum(live, dtype=jnp.int32)
                    - jnp.sum(ok, dtype=jnp.int32)).reshape(1)
-        return found, v, ok, dropped, deferred
+        return found, v, ok, dropped, deferred, steps
 
     spec = P(axis)
     fn = jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(spec,) * 6, out_specs=(spec,) * 5,
+        body, mesh=mesh, in_specs=(spec,) * 6, out_specs=(spec,) * 6,
         check_vma=False))
     return _mapped_cache_put(key, fn)
 
@@ -707,12 +714,16 @@ class SetResult(NamedTuple):
     cue to the displacer stage; every such row resolves to 1/2/4/5
     within the same call (the escalation re-dispatch provably cannot
     drop), so callers never observe it.  ``applied`` acks the rows the
-    device arrays absorbed."""
+    device arrays absorbed.  ``scanned`` and ``escalated`` (steady-state
+    paths only) count the live rows each owner's writer scan ran and the
+    rows it re-ran through the displacer."""
     status: jnp.ndarray     # (S, B) int32 — the path taken per request
     applied: jnp.ndarray    # (S, B) bool — committed to the device arrays
     ok: jnp.ndarray         # (S, B) bool — response authoritative
     dropped: jnp.ndarray    # (S,) int32
     deferred: jnp.ndarray   # (S,) int32
+    scanned: Optional[jnp.ndarray] = None    # (S,) int32 at the owner
+    escalated: Optional[jnp.ndarray] = None  # (S,) int32 at the owner
 
     def __repr__(self):
         return _mutation_repr("SetResult", self)
@@ -735,37 +746,33 @@ class DeleteResult(NamedTuple):
         return _mutation_repr("DeleteResult", self)
 
 
-def _writer_set_local(keys, vals, qk, qv, live, *, n_shards, capacity, axis,
-                      neighborhood, val_words, max_steps, max_search,
-                      max_moves):
-    """Owner-side SET serving: the pre-posted writer chain CAS-claims /
-    updates buckets; requests against one shard are serialized so each
-    chain observes its predecessors' writes (no host lookup anywhere).
+def _counted(step):
+    """``step`` with a count of the live rows it ran carried beside its
+    own carry: ``((carry, n), xs)``.  A row is live when its key word is
+    not EMPTY (zero-padded window slots and undispatched rows are not);
+    ``xs`` may be a payload row, a lap of rows, or ``(payload, fault)``."""
+    def run(carry, xs):
+        inner, n = carry
+        pay = xs[0] if isinstance(xs, tuple) else xs
+        inner, out = step(inner, xs)
+        return (inner, n + jnp.sum(pay[..., 0] != hopscotch.EMPTY,
+                                   dtype=jnp.int32)), out
+    return run
 
-    Rows the fast writer answers ``SET_NEEDS_DISPLACEMENT`` re-run
-    through the *displacer* chain as a second stateful stage (same
-    dispatch/scan/combine pattern, one more RTT for just those rows):
-    the bounded hopscotch bubble executes on-device, so a
-    neighborhood-full insert needs no host either.  The escalation
-    re-dispatch can never drop: stage-2 live rows are a subset of
-    stage-1's admitted rows, and ``rank_within_dest`` ranks only live
-    rows, so every stage-2 rank is <= its stage-1 rank < capacity.
-    """
-    q = qk.reshape(-1)
-    dest = shard_of(q, n_shards)
-    n_buckets = keys.shape[1]
-    lv = live.reshape(-1)
-    writer = programs.build_hopscotch_writer(n_buckets, val_words,
-                                             neighborhood)
-    payload = writer.device_payloads(q, hopscotch.bucket_of(q, n_buckets),
-                                     qv.reshape(-1, val_words))
 
-    resp, ok, (nk, nv) = transport.triggered_chain_stateful(
-        _guarded_step(writer.run_one, max_steps), (keys[0], vals[0]),
-        payload, dest, n_shards, capacity, axis, 1, lv)
-    status = resp[:, 0]
-    live2 = ok & (status == programs.SET_NEEDS_DISPLACEMENT)
-
+def _displace_stage(nk, nv, q, qv, dest, live2, status, *, n_shards,
+                    capacity, axis, neighborhood, val_words, max_steps,
+                    max_search, max_moves):
+    """The SET path's escalation: rows the writer stage answered
+    ``SET_NEEDS_DISPLACEMENT`` (``live2``) re-run through the
+    *displacer* chain as a second stateful stage (same
+    dispatch/scan/combine pattern, one more RTT for just those rows): the
+    bounded hopscotch bubble executes on-device, so a neighborhood-full
+    insert needs no host either.  The re-dispatch can never drop: stage-2
+    live rows are a subset of stage-1's admitted rows, and
+    ``rank_within_dest`` ranks only live rows, so every stage-2 rank is
+    <= its stage-1 rank < capacity.  Returns ``(status, nk, nv,
+    escalated)``, ``escalated`` the rows this owner's displacer ran."""
     if neighborhood < 2 or max_search < neighborhood:
         # degenerate geometries the displacer cannot be built for — an
         # H=1 bubble's window [free-H+1, free) is empty, and a search
@@ -776,9 +783,9 @@ def _writer_set_local(keys, vals, qk, qv, live, *, n_shards, capacity, axis,
         # without building a displacer.
         status = jnp.where(live2, jnp.int32(programs.SET_NEEDS_RESIZE),
                            status)
-        return status[None], ok[None], nk[None], nv[None]
+        return status, nk, nv, jnp.zeros((), jnp.int32)
 
-    # --- escalation: the displacement bubble, still on-chain --------------
+    n_buckets = nk.shape[0]
     disp = programs.build_hopscotch_displacer(
         n_buckets, val_words, neighborhood, max_search, max_moves)
     payload2 = disp.device_payloads(q, hopscotch.bucket_of(q, n_buckets),
@@ -788,13 +795,51 @@ def _writer_set_local(keys, vals, qk, qv, live, *, n_shards, capacity, axis,
     # no tunable geometry can exhaust fuel mid-bubble and misreport a
     # placeable key as needs-resize
     disp_steps = max(max_steps, disp.fuel)
-    step2 = _guarded_step(disp.run_one, disp_steps)
-
-    resp2, ok2, (nk, nv) = transport.triggered_chain_stateful(
-        step2, (nk, nv), payload2, dest, n_shards, capacity, axis, 1,
-        live2)
+    with obs.scope("kv.set.scan"):
+        resp2, ok2, ((nk, nv), escalated) = (
+            transport.triggered_chain_stateful(
+                _counted(_guarded_step(disp.run_one, disp_steps)),
+                ((nk, nv), jnp.int32(0)), payload2, dest, n_shards,
+                capacity, axis, 1, live2))
     status = jnp.where(live2 & ok2, resp2[:, 0], status)
-    return status[None], ok[None], nk[None], nv[None]
+    return status, nk, nv, escalated
+
+
+def _writer_set_local(keys, vals, qk, qv, live, *, n_shards, capacity, axis,
+                      neighborhood, val_words, max_steps, max_search,
+                      max_moves):
+    """Owner-side SET serving: the pre-posted writer chain CAS-claims /
+    updates buckets; requests against one shard are serialized so each
+    chain observes its predecessors' writes (no host lookup anywhere).
+    Rows the fast writer answers ``SET_NEEDS_DISPLACEMENT`` escalate to
+    the displacer chain (:func:`_displace_stage`).
+
+    Returns ``(status, ok, keys, vals, scanned, escalated)``: the last
+    two count the live rows this owner's writer and displacer scans ran.
+    """
+    q = qk.reshape(-1)
+    dest = shard_of(q, n_shards)
+    n_buckets = keys.shape[1]
+    lv = live.reshape(-1)
+    writer = programs.build_hopscotch_writer(n_buckets, val_words,
+                                             neighborhood)
+    payload = writer.device_payloads(q, hopscotch.bucket_of(q, n_buckets),
+                                     qv.reshape(-1, val_words))
+
+    with obs.scope("kv.set.scan"):
+        resp, ok, ((nk, nv), scanned) = transport.triggered_chain_stateful(
+            _counted(_guarded_step(writer.run_one, max_steps)),
+            ((keys[0], vals[0]), jnp.int32(0)), payload, dest, n_shards,
+            capacity, axis, 1, lv)
+    status = resp[:, 0]
+    live2 = ok & (status == programs.SET_NEEDS_DISPLACEMENT)
+    status, nk, nv, escalated = _displace_stage(
+        nk, nv, q, qv, dest, live2, status, n_shards=n_shards,
+        capacity=capacity, axis=axis, neighborhood=neighborhood,
+        val_words=val_words, max_steps=max_steps, max_search=max_search,
+        max_moves=max_moves)
+    return (status[None], ok[None], nk[None], nv[None], scanned.reshape(1),
+            escalated.reshape(1))
 
 
 def _writer_set_local_faulted(keys, vals, qk, qv, live, frows, *, n_shards,
@@ -827,29 +872,22 @@ def _writer_set_local_faulted(keys, vals, qk, qv, live, frows, *, n_shards,
     payload = writer.device_payloads(q, hopscotch.bucket_of(q, n_buckets),
                                      qv.reshape(-1, val_words))
 
-    resp, ok, (nk, nv) = transport.triggered_chain_stateful(
-        _guarded_step(writer.run_one, max_steps, writer.run_one_faulted),
-        (keys[0], vals[0]), payload, dest, n_shards, capacity, axis, 1,
-        lv, faults=fr)
+    with obs.scope("kv.set.scan"):
+        resp, ok, ((nk, nv), scanned) = transport.triggered_chain_stateful(
+            _counted(_guarded_step(writer.run_one, max_steps,
+                                   writer.run_one_faulted)),
+            ((keys[0], vals[0]), jnp.int32(0)), payload, dest, n_shards,
+            capacity, axis, 1, lv, faults=fr)
     status = resp[:, 0]
     armed = faults_mod.FaultPlan.from_row(fr).active()
     live2 = ok & (status == programs.SET_NEEDS_DISPLACEMENT) & ~armed
-
-    if neighborhood < 2 or max_search < neighborhood:
-        status = jnp.where(live2, jnp.int32(programs.SET_NEEDS_RESIZE),
-                           status)
-        return status[None], ok[None], nk[None], nv[None]
-
-    disp = programs.build_hopscotch_displacer(
-        n_buckets, val_words, neighborhood, max_search, max_moves)
-    payload2 = disp.device_payloads(q, hopscotch.bucket_of(q, n_buckets),
-                                    qv.reshape(-1, val_words))
-    disp_steps = max(max_steps, disp.fuel)
-    resp2, ok2, (nk, nv) = transport.triggered_chain_stateful(
-        _guarded_step(disp.run_one, disp_steps), (nk, nv), payload2,
-        dest, n_shards, capacity, axis, 1, live2)
-    status = jnp.where(live2 & ok2, resp2[:, 0], status)
-    return status[None], ok[None], nk[None], nv[None]
+    status, nk, nv, escalated = _displace_stage(
+        nk, nv, q, qv, dest, live2, status, n_shards=n_shards,
+        capacity=capacity, axis=axis, neighborhood=neighborhood,
+        val_words=val_words, max_steps=max_steps, max_search=max_search,
+        max_moves=max_moves)
+    return (status[None], ok[None], nk[None], nv[None], scanned.reshape(1),
+            escalated.reshape(1))
 
 
 def _mw_set_local(keys, vals, qk, qv, live, *, n_shards, capacity, axis,
@@ -889,27 +927,19 @@ def _mw_set_local(keys, vals, qk, qv, live, *, n_shards, capacity, axis,
         status, nk, nv = group.run_group(*carry, lap, sched, gsteps)
         return (nk, nv), status[:, None]
 
-    resp, ok, (nk, nv) = transport.triggered_chain_group(
-        group_fn, (keys[0], vals[0]), payload, dest, n_shards, capacity,
-        axis, 1, n_writers, lv)
+    with obs.scope("kv.set.scan"):
+        resp, ok, ((nk, nv), scanned) = transport.triggered_chain_group(
+            _counted(group_fn), ((keys[0], vals[0]), jnp.int32(0)),
+            payload, dest, n_shards, capacity, axis, 1, n_writers, lv)
     status = resp[:, 0]
     live2 = ok & (status == programs.SET_NEEDS_DISPLACEMENT)
-
-    if neighborhood < 2 or max_search < neighborhood:
-        status = jnp.where(live2, jnp.int32(programs.SET_NEEDS_RESIZE),
-                           status)
-        return status[None], ok[None], nk[None], nv[None]
-
-    disp = programs.build_hopscotch_displacer(
-        n_buckets, val_words, neighborhood, max_search, max_moves)
-    payload2 = disp.device_payloads(q, hopscotch.bucket_of(q, n_buckets),
-                                    qv.reshape(-1, val_words))
-    disp_steps = max(max_steps, disp.fuel)
-    resp2, ok2, (nk, nv) = transport.triggered_chain_stateful(
-        _guarded_step(disp.run_one, disp_steps), (nk, nv), payload2,
-        dest, n_shards, capacity, axis, 1, live2)
-    status = jnp.where(live2 & ok2, resp2[:, 0], status)
-    return status[None], ok[None], nk[None], nv[None]
+    status, nk, nv, escalated = _displace_stage(
+        nk, nv, q, qv, dest, live2, status, n_shards=n_shards,
+        capacity=capacity, axis=axis, neighborhood=neighborhood,
+        val_words=val_words, max_steps=max_steps, max_search=max_search,
+        max_moves=max_moves)
+    return (status[None], ok[None], nk[None], nv[None], scanned.reshape(1),
+            escalated.reshape(1))
 
 
 def relocate_exp(old_keys: jnp.ndarray, old_exp: jnp.ndarray,
@@ -1067,16 +1097,15 @@ def _set_table(mesh: Mesh, axis: str, keys: jnp.ndarray, vals: jnp.ndarray,
     mapped = _mapped_set(mesh, axis, n_shards, capacity, neighborhood,
                          vals.shape[-1], max_steps, max_search, max_moves,
                          faulted=faults is not None, n_writers=n_writers)
+    args = (keys, vals, set_keys, set_vals, live)
     if faults is not None:
-        status, ok, dropped, deferred, nk, nv = mapped(
-            keys, vals, set_keys, set_vals, live, faults.as_rows())
-    else:
-        status, ok, dropped, deferred, nk, nv = mapped(keys, vals, set_keys,
-                                                       set_vals, live)
+        args += (faults.as_rows(),)
+    status, ok, dropped, deferred, nk, nv, scanned, escalated = mapped(*args)
     applied = ok & ((status == programs.SET_UPDATED)
                     | (status == programs.SET_INSERTED)
                     | (status == programs.SET_DISPLACED))
-    result = SetResult(status, applied, ok, dropped, deferred)
+    result = SetResult(status, applied, ok, dropped, deferred, scanned,
+                       escalated)
     if exp is not None:
         # deadline follow-up is commit-layer state: the writer/displacer
         # chains may have relocated keys, so re-home the column by key
@@ -1120,32 +1149,21 @@ def _mapped_set(mesh: Mesh, axis: str, n_shards: int, capacity: int,
             max_steps=max_steps, max_search=max_search,
             max_moves=max_moves)
 
-    if faulted:
-        def body(keys, vals, qk, qv, live, frows):
-            real = qk != hopscotch.EMPTY
-            live = live & real
-            status, ok, nk, nv = path(keys, vals, qk, qv, live, frows)
-            deferred = jnp.sum(~live & real, dtype=jnp.int32).reshape(1)
-            dropped = (jnp.sum(live, dtype=jnp.int32)
-                       - jnp.sum(ok, dtype=jnp.int32)).reshape(1)
-            return status, ok, dropped, deferred, nk, nv
-        n_in = 6
-    else:
-        def body(keys, vals, qk, qv, live):
-            # unused (key-0) slots are inert: no dispatch slot, no counter
-            real = qk != hopscotch.EMPTY
-            live = live & real
-            status, ok, nk, nv = path(keys, vals, qk, qv, live)
-            deferred = jnp.sum(~live & real, dtype=jnp.int32).reshape(1)
-            dropped = (jnp.sum(live, dtype=jnp.int32)
-                       - jnp.sum(ok, dtype=jnp.int32)).reshape(1)
-            return status, ok, dropped, deferred, nk, nv
-        n_in = 5
+    def body(keys, vals, qk, qv, live, *frows):
+        # unused (key-0) slots are inert: no dispatch slot, no counter
+        real = qk != hopscotch.EMPTY
+        live = live & real
+        status, ok, nk, nv, scanned, escalated = path(keys, vals, qk, qv,
+                                                      live, *frows)
+        deferred = jnp.sum(~live & real, dtype=jnp.int32).reshape(1)
+        dropped = (jnp.sum(live, dtype=jnp.int32)
+                   - jnp.sum(ok, dtype=jnp.int32)).reshape(1)
+        return status, ok, dropped, deferred, nk, nv, scanned, escalated
 
     spec = P(axis)
     fn = jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(spec,) * n_in, out_specs=(spec,) * 6,
-        check_vma=False))
+        body, mesh=mesh, in_specs=(spec,) * (6 if faulted else 5),
+        out_specs=(spec,) * 8, check_vma=False))
     return _mapped_cache_put(key, fn)
 
 
@@ -1629,7 +1647,7 @@ def _mig_get_local(ok, ov, nk, nv, wm, queries, live, *, n_shards,
                                               neighborhood)
     st_new = srv_new.device_state(nk[0], nv[0])
     pay_new = srv_new.device_payloads(q, hopscotch.bucket_of(q, 2 * n))
-    resp1, ok1 = transport.triggered_chain_engine(
+    resp1, ok1, _ = transport.triggered_chain_engine(
         srv_new.engine, st_new, srv_new.recv_wq, srv_new.resp_region,
         srv_new.resp_words, pay_new, dest, n_shards, capacity, axis, lv)
     found1 = resp1[:, 0] > 0
@@ -1644,7 +1662,7 @@ def _mig_get_local(ok, ov, nk, nv, wm, queries, live, *, n_shards,
     srv_old = programs.build_hopscotch_server(n, val_words, neighborhood)
     st_old = srv_old.device_state(ok[0], ov[0])
     pay_old = srv_old.device_payloads(q, h_old)
-    resp2, _ = transport.triggered_chain_engine(
+    resp2, _, _ = transport.triggered_chain_engine(
         srv_old.engine, st_old, srv_old.recv_wq, srv_old.resp_region,
         srv_old.resp_words, pay_old, dest, n_shards, capacity, axis, live2)
     found2 = resp2[:, 0] > 0

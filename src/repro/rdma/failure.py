@@ -19,6 +19,7 @@ from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import obs
 from ..core import faults as faults_mod
 from ..core import programs
 # module alias, not from-import of names: kvstore.store itself imports
@@ -159,6 +160,13 @@ class ShardedKVService(_HostDriverLifecycle):
     # stamped while the frames were doubled — folded back at cutover.
     _exp_keys: object = None
     _pending_deadlines: dict = dataclasses.field(default_factory=dict)
+    # -- tracing (repro.obs): serving calls made, and the device counters
+    # of the last call made while tracing, read when the next call begins
+    _seq: int = dataclasses.field(default=0, init=False)
+    _counters: Optional[tuple] = dataclasses.field(default=None, init=False)
+
+    def __post_init__(self):
+        obs.install_gc_spans()
 
     @classmethod
     def start(cls, items: Sequence[Tuple[int, Sequence[int]]],
@@ -193,8 +201,53 @@ class ShardedKVService(_HostDriverLifecycle):
                 np.zeros((n_shards,), np.int32), sharding)
         return svc
 
+    # -- tracing: spans and counters of the serving calls ---------------------
+    def _begin_call(self) -> int:
+        """Emit the previous traced call's counters as its ``kv.counters``
+        span, then number this call.  The read comes before this call's
+        own span opens, and in a closed loop the previous call's answers
+        are on the host already, so it waits on no running program."""
+        pending, self._counters = self._counters, None
+        if pending is not None and obs.enabled():
+            seq, kind, arrays = pending
+            counts = [np.asarray(a) for a in arrays]
+            if kind == "get":
+                steps, = counts                  # (S, S * capacity)
+                trips = steps.max(axis=1)
+                obs.mark("kv.counters", seq=seq, kind=kind,
+                         trips=int(trips.max()), steps=int(steps.sum()),
+                         lanes=int((trips * steps.shape[1]).sum()))
+            else:
+                scanned, escalated = counts      # (S,) each
+                obs.mark("kv.counters", seq=seq, kind=kind,
+                         scanned=int(scanned.sum()),
+                         scanned_max=int(scanned.max()),
+                         escalated=int(escalated.sum()))
+        self._seq += 1
+        return self._seq
+
+    def _hold_counters(self, seq: int, kind: str, *arrays):
+        if obs.enabled() and arrays[0] is not None:
+            self._counters = (seq, kind, arrays)
+
+    def _sync(self, what: str, x) -> np.ndarray:
+        """A host read of device state on the served path, as a
+        ``kv.sync`` span."""
+        with obs.span("kv.sync", what=what):
+            return np.asarray(x)
+
     # -- the serving path (pure device state) --------------------------------
     def get_many(self, queries, now=None, **kwargs) -> "kv_store.GetResult":
+        """Sharded redn gets: see :meth:`_get_many`.  While a profiler
+        trace is active the call is a ``kv.get_many`` span, and its VM
+        steps are emitted when the next call begins (:mod:`repro.obs`)."""
+        seq = self._begin_call()
+        with obs.span("kv.get_many", seq=seq, width=int(np.size(queries))):
+            res = self._get_many(queries, now, **kwargs)
+        self._hold_counters(seq, "get", res.vm_steps)
+        return res
+
+    def _get_many(self, queries, now=None, **kwargs) -> "kv_store.GetResult":
         """Sharded redn gets: chain programs execute at the owner shards.
         Works with the driver dead — no host state is touched.  While a
         resize is in flight the store serves from the double frame
@@ -242,10 +295,8 @@ class ShardedKVService(_HostDriverLifecycle):
         if not expired.any():
             return res
         keep = jnp.asarray(~expired)
-        return kv_store.GetResult(
-            res.found & keep,
-            jnp.where(keep[..., None], res.values, 0),
-            res.ok, res.dropped, res.deferred)
+        return res._replace(found=res.found & keep,
+                            values=jnp.where(keep[..., None], res.values, 0))
 
     def _deadline_map(self) -> dict:
         """key -> deadline as of the resize window (snapshot + stamps)."""
@@ -260,6 +311,17 @@ class ShardedKVService(_HostDriverLifecycle):
 
     def set_many(self, set_keys, set_vals, deadlines=None,
                  **kwargs) -> "kv_store.SetResult":
+        """Batched chain-offloaded sets: see :meth:`_set_many`.  While a
+        profiler trace is active the call is a ``kv.set_many`` span, and
+        its scan counts are emitted when the next call begins."""
+        seq = self._begin_call()
+        with obs.span("kv.set_many", seq=seq, width=int(np.size(set_keys))):
+            res = self._set_many(set_keys, set_vals, deadlines, **kwargs)
+        self._hold_counters(seq, "set", res.scanned, res.escalated)
+        return res
+
+    def _set_many(self, set_keys, set_vals, deadlines=None,
+                  **kwargs) -> "kv_store.SetResult":
         """Batched chain-offloaded sets: the writer chain programs execute
         at the owner shards against the authoritative device arrays, and
         neighborhood-full rows escalate to the displacer chain in the
@@ -316,7 +378,7 @@ class ShardedKVService(_HostDriverLifecycle):
             return res
         # (materializing status here is a host sync — only pay it when
         # the answer can actually change the control flow)
-        needs = np.asarray(res.status) == programs.SET_NEEDS_RESIZE
+        needs = self._sync("status", res.status) == programs.SET_NEEDS_RESIZE
         if not needs.any():
             return res
         # --- auto-escalation: grow, then land the unplaced rows ----------
@@ -332,12 +394,10 @@ class ShardedKVService(_HostDriverLifecycle):
             **rekw)
         self._stamp_pending(res2.applied, qk, deadlines)
         self._advance_resize()
-        status = jnp.where(retry, res2.status, res.status)
-        ok = jnp.where(retry, res2.ok, res.ok)
-        applied = res.applied | res2.applied
-        return kv_store.SetResult(status, applied, ok,
-                                  res.dropped + res2.dropped,
-                                  res.deferred)
+        return res._replace(status=jnp.where(retry, res2.status, res.status),
+                            applied=res.applied | res2.applied,
+                            ok=jnp.where(retry, res2.ok, res.ok),
+                            dropped=res.dropped + res2.dropped)
 
     # -- resize-window TTL bookkeeping (commit-layer, host-held) -------------
     def _park_exp(self):
@@ -447,12 +507,12 @@ class ShardedKVService(_HostDriverLifecycle):
     def _advance_resize(self, step: Optional[int] = None):
         if self.resize is None:
             return
-        before = int(np.asarray(self.resize.watermark).min())
+        before = int(self._sync("resize", self.resize.watermark).min())
         self.resize, report = kv_store.sharded_resize(
             self.mesh, self.axis, self.resize,
             step=step or self.resize_quantum)
-        after = int(np.asarray(self.resize.watermark).min())
-        if after == before and int(np.asarray(report.stuck).sum()):
+        after = int(self._sync("resize", self.resize.watermark).min())
+        if after == before and int(self._sync("resize", report.stuck).sum()):
             # the watermark parks exactly on the bucket the quantum
             # could not place.  PR 5 raised ResizeStuck here — a capacity
             # dead end the operator had to resolve.  Now the dead end

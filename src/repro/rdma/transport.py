@@ -34,6 +34,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import obs
+
 
 def rank_within_dest(dest: jnp.ndarray,
                      live: Optional[jnp.ndarray] = None) -> jnp.ndarray:
@@ -75,16 +77,17 @@ def dispatch(payload: jnp.ndarray, dest: jnp.ndarray, n_shards: int,
              authoritative and must not be read as a miss.
     """
     b, w = payload.shape
-    pos = rank_within_dest(dest, live)
-    ok = pos < capacity
-    if live is not None:
-        ok = ok & live
-    send = jnp.zeros((n_shards, capacity, w), payload.dtype)
-    # not-ok rows get an out-of-range slot and are dropped by scatter
-    slot = jnp.where(ok, pos, capacity)
-    send = send.at[dest, slot].set(payload, mode="drop")
-    recv = lax.all_to_all(send, axis_name, split_axis=0, concat_axis=0,
-                          tiled=False)
+    with obs.scope("kv.route"):
+        pos = rank_within_dest(dest, live)
+        ok = pos < capacity
+        if live is not None:
+            ok = ok & live
+        send = jnp.zeros((n_shards, capacity, w), payload.dtype)
+        # not-ok rows get an out-of-range slot and are dropped by scatter
+        slot = jnp.where(ok, pos, capacity)
+        send = send.at[dest, slot].set(payload, mode="drop")
+        recv = lax.all_to_all(send, axis_name, split_axis=0, concat_axis=0,
+                              tiled=False)
     return recv, pos, ok
 
 
@@ -98,13 +101,14 @@ def combine(responses: jnp.ndarray, dest: jnp.ndarray, pos: jnp.ndarray,
     (their content is meaningless — the caller must consult ``ok``, which
     is what keeps drops from aliasing with misses).
     """
-    back = lax.all_to_all(responses, axis_name, split_axis=0, concat_axis=0,
-                          tiled=False)
-    # back[s, c] = response from shard s for my c-th request to it
-    capacity = back.shape[1]
-    safe = jnp.minimum(pos, capacity - 1)
-    out = back[dest, safe]
-    return out * ok[:, None].astype(out.dtype)
+    with obs.scope("kv.route"):
+        back = lax.all_to_all(responses, axis_name, split_axis=0,
+                              concat_axis=0, tiled=False)
+        # back[s, c] = response from shard s for my c-th request to it
+        capacity = back.shape[1]
+        safe = jnp.minimum(pos, capacity - 1)
+        out = back[dest, safe]
+        return out * ok[:, None].astype(out.dtype)
 
 
 def one_sided_read(remote: jnp.ndarray, shard: jnp.ndarray,
@@ -321,13 +325,16 @@ def triggered_chain_engine(engine, state, recv_wq: int, resp_region: int,
     ``ChainEngine.run_many`` call — the chain, not the host, computes the
     answer.  The caller pays exactly one dispatch/combine pair (1 RTT)
     regardless of the chain's complexity — the paper's core performance
-    claim.  Returns (responses (B, resp_words), ok (B,)): each response is
-    the context's ``resp_region`` snapshot after its chain quiesced.
+    claim.  Returns (responses (B, resp_words), ok (B,), steps
+    (n_shards * capacity,)): each response is the context's
+    ``resp_region`` snapshot after its chain quiesced, and ``steps`` the
+    WRs each context of the owner's receive window executed.
     """
     recv, pos, ok = dispatch(payload, dest, n_shards, capacity, axis_name,
                              live)
     flat = recv.reshape(-1, recv.shape[-1])
-    out = engine.run_many(state, recv_wq, flat, max_steps)
+    with obs.scope("kv.get.vm"):
+        out = engine.run_many(state, recv_wq, flat, max_steps)
     resp = out.mem[:, resp_region:resp_region + resp_words]
     resp = resp.reshape(n_shards, capacity, resp_words)
-    return combine(resp, dest, pos, ok, axis_name), ok
+    return combine(resp, dest, pos, ok, axis_name), ok, out.steps
