@@ -2,14 +2,16 @@
 dispatch and combine: rank sort, scatter, all-to-all and gather) inside
 each ``bench.get`` call, mean over chips, averaged over the window's GET
 calls whose ``kv.get.vm`` ops the trace holds."""
+from bench.metrics import _program
 
 
 def reduce(traced):
-    if not traced.scoped:
+    program = _program.of(traced)
+    if not program.scoped:
         return None
-    t = [traced.scoped_in("kv.route", s.start, s.end)
+    t = [program.scoped_in("kv.route", s.start, s.end)
          for s in traced.calls("bench.get")
-         if traced.scoped_in("kv.get.vm", s.start, s.end)]
+         if program.scoped_in("kv.get.vm", s.start, s.end)]
     if not t:
         return None
     return sum(t) / len(t) * 1e-6

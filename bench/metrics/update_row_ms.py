@@ -4,16 +4,18 @@ the largest number of live rows any owner's scan ran in that call (its
 ``kv.counters`` span).  Averaged over the window's SET calls whose
 counters were emitted, that scanned a row, and whose ``kv.set.scan`` ops
 the trace holds."""
+from bench.metrics import _program
 
 
 def reduce(traced):
-    if not traced.scoped:
+    program = _program.of(traced)
+    if not program.scoped:
         return None
-    counters = traced.counters("set")
+    counters = program.counters("set")
     per_row = []
     for call in traced.calls("bench.set"):
-        c = counters.get(traced.call_seq(call, "kv.set_many"))
-        scan = traced.scoped_in("kv.set.scan", call.start, call.end)
+        c = counters.get(program.call_seq(call, "kv.set_many"))
+        scan = program.scoped_in("kv.set.scan", call.start, call.end)
         if c and c["scanned_max"] > 0 and scan > 0:
             per_row.append(scan / c["scanned_max"])
     if not per_row:

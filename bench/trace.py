@@ -10,11 +10,6 @@ busy while a program runs on it: the union of its ``XLA Modules``
 intervals.  Of its ``XLA Ops`` only the top-level ops (for the breakdown)
 and the all-to-all ops are kept: ops inside a loop body repeat once per
 iteration and number hundreds of thousands a second.
-
-The program's own instrumentation (``repro.obs``) is kept beside them:
-its host spans (``kv.*``, ``host.*``) in ``Traced.program``, and the
-top-level ops that ran inside one of its ``kv.*`` device scopes, with
-that scope, in ``Traced.scoped``.
 """
 from __future__ import annotations
 
@@ -30,8 +25,6 @@ DEVICE_PLANE = "/device:TPU:"
 MODULES_LINE = "XLA Modules"
 OPS_LINE = "XLA Ops"
 SPAN_PREFIX = "bench."
-PROGRAM_PREFIXES = ("kv.", "host.")
-SCOPE_PREFIX = "kv."
 
 
 class Tracer:
@@ -105,10 +98,6 @@ class Traced:
     peak: Optional[dict] = None  # row of bench/peaks.json
     neighborhood: int = 8
     val_words: int = 4
-    # the program's host spans, in start order
-    program: list = dataclasses.field(default_factory=list)
-    # chip -> (scopes, starts, ends) of top-level ops in a kv.* scope
-    scoped: dict = dataclasses.field(default_factory=dict)
 
     def __post_init__(self):
         self._busy = {c: _merge(s, e) for c, (s, e) in self.modules.items()}
@@ -155,39 +144,6 @@ class Traced:
     def busy_s(self) -> float:
         return self.busy_in(*self.window) * 1e-9
 
-    # -- the program's own spans, scopes and counters ----------------------
-    def program_in(self, name: str, a: float, b: float) -> list:
-        """The program's spans called ``name`` that lie in ``[a, b]``."""
-        return [s for s in self.program
-                if s.name == name and s.start >= a and s.end <= b]
-
-    def program_calls(self) -> list:
-        """``kv.get_many``/``kv.set_many`` spans inside the window."""
-        return (self.program_in("kv.get_many", *self.window)
-                + self.program_in("kv.set_many", *self.window))
-
-    def counters(self, kind: str) -> dict:
-        """seq -> arguments of the ``kv.counters`` spans of ``kind``."""
-        return {s.args["seq"]: s.args for s in self.program
-                if s.name == "kv.counters" and s.args.get("kind") == kind}
-
-    def call_seq(self, call: Span, name: str) -> Optional[int]:
-        """``seq`` of the program span ``name`` inside a ``bench.*`` call."""
-        inner = self.program_in(name, call.start, call.end)
-        return inner[0].args.get("seq") if inner else None
-
-    def scoped_in(self, scope: str, a: float, b: float) -> float:
-        """ns in ``[a, b]`` during which an op of device scope ``scope``
-        ran, mean over chips."""
-        if not self.scoped:
-            return 0.0
-        covered = []
-        for scopes, s, e in self.scoped.values():
-            pick = np.asarray([x == scope for x in scopes], bool)
-            covered.append(_covered(_merge(s[pick], e[pick]), a, b)
-                           if pick.any() else 0.0)
-        return float(np.mean(covered))
-
     def breakdown(self, top: int = 10) -> dict:
         """The top-level device ops that took most time (seconds, mean over
         chips) and the longest idle gaps, named by the host span open in
@@ -228,9 +184,6 @@ class Traced:
             "ops": {c: [list(n), arr(s), arr(e)] for c, (n, s, e)
                     in self.ops.items()},
             "a2a": {c: [arr(s), arr(e)] for c, (s, e) in self.a2a.items()},
-            "program": [dataclasses.asdict(s) for s in self.program],
-            "scoped": {c: [list(n), arr(s), arr(e)] for c, (n, s, e)
-                       in self.scoped.items()},
         }
 
     @classmethod
@@ -243,10 +196,7 @@ class Traced:
                     in doc["ops"].items()},
                    {int(c): (f(s), f(e)) for c, (s, e)
                     in doc["a2a"].items()},
-                   peak, neighborhood, val_words,
-                   [Span(**s) for s in doc.get("program", [])],
-                   {int(c): (list(n), f(s), f(e)) for c, (n, s, e)
-                    in doc.get("scoped", {}).items()})
+                   peak, neighborhood, val_words)
 
 
 def _stats(event) -> dict:
@@ -279,145 +229,21 @@ def _device_line(line, keep_all: bool, pick=None):
     return names, f(starts), f(ends), f(pick_s), f(pick_e)
 
 
-# -- the scopes of the programs' ops, from their HLO ---------------------------
-# ``ProfileData`` gives neither an op's own metadata nor the programs' HLO,
-# where the op's scope path lives (``OpMetadata.op_name``:
-# ``jit(body)/kv.route/sort``), so these read the HLO from the file's
-# protobuf wire format.  The ``/host:metadata`` plane holds an event
-# metadata per program, named as its ``XLA Modules`` events, whose stat
-# ``Hlo Proto`` is the program's HloProto.  Field numbers, from
-# tsl/profiler/protobuf/xplane.proto: XSpace.planes 1; XPlane.name 2,
-# .event_metadata 4, .stat_metadata 5 (map entries: key 1, value 2);
-# XEventMetadata.name 2, .stats 5; XStatMetadata.name 2;
-# XStat.metadata_id 1, .bytes_value 6.  From xla/service/hlo.proto:
-# HloProto.hlo_module 1; HloModuleProto.entry_computation_name 2,
-# .computations 3; HloComputationProto.name 1, .instructions 2;
-# HloInstructionProto.name 1, .metadata 7; OpMetadata.op_name 2.
-METADATA_PLANE = "/host:metadata"
-HLO_STAT = "Hlo Proto"
-
-
-def _varint(buf, i: int):
-    x = shift = 0
-    while True:
-        b = buf[i]
-        i += 1
-        x |= (b & 0x7F) << shift
-        if b < 0x80:
-            return x, i
-        shift += 7
-
-
-def _fields(buf, start: int, end: int):
-    """``(field, value)`` of one message: an int for a varint, a
-    ``(start, end)`` span for a length-delimited value, None for a fixed
-    width one."""
-    i = start
-    while i < end:
-        key, i = _varint(buf, i)
-        wire = key & 7
-        if wire == 0:
-            v, i = _varint(buf, i)
-        elif wire == 2:
-            n, i = _varint(buf, i)
-            v, i = (i, i + n), i + n
-        elif wire in (1, 5):
-            v, i = None, i + (8 if wire == 1 else 4)
-        else:
-            raise ValueError(f"protobuf wire type {wire} at byte {i}")
-        yield key >> 3, v
-
-
-def _text(buf, span) -> str:
-    return bytes(buf[span[0]:span[1]]).decode("utf-8", "replace")
-
-
-def _innermost(path: str) -> Optional[str]:
-    inner = [p for p in path.split("/") if p.startswith(SCOPE_PREFIX)]
-    return inner[-1] if inner else None
-
-
-def _entry_scopes(buf, hlo_proto) -> dict:
-    """instruction -> innermost ``kv.*`` scope, over the entry computation
-    of one HloProto (its top-level ops)."""
-    module = dict(_fields(buf, *hlo_proto)).get(1)
-    fields = list(_fields(buf, *module)) if module else []
-    entry = next((_text(buf, v) for f, v in fields if f == 2), None)
-    out = {}
-    for f, comp in fields:
-        body = list(_fields(buf, *comp)) if f == 3 else []
-        name = next((_text(buf, v) for g, v in body if g == 1), None)
-        if entry is not None and name != entry:
-            continue
-        for g, ins in body:
-            d = dict(_fields(buf, *ins)) if g == 2 else {}
-            meta = dict(_fields(buf, *d[7])) if 7 in d else {}
-            scope = _innermost(_text(buf, meta[2])) if 2 in meta else None
-            if scope is not None:
-                out[_text(buf, d[1])] = scope
-    return out
-
-
-def program_scopes(xplane) -> dict:
-    """``{program: {top-level instruction: kv.* scope}}`` for every
-    program whose HLO the trace holds."""
-    buf = memoryview(pathlib.Path(xplane).read_bytes())
-    out = {}
-    for f, plane in _fields(buf, 0, len(buf)):
-        fields = list(_fields(buf, *plane)) if f == 1 else []
-        if not any(g == 2 and _text(buf, v) == METADATA_PLANE
-                   for g, v in fields):
-            continue
-        entries = [(g, dict(_fields(buf, *v))) for g, v in fields
-                   if g in (4, 5)]
-        hlo_ids = {e[1] for g, e in entries if g == 5 and 2 in e
-                   and _text(buf, dict(_fields(buf, *e[2])).get(2, (0, 0)))
-                   == HLO_STAT}
-        for g, e in entries:
-            meta = list(_fields(buf, *e[2])) if g == 4 and 2 in e else []
-            name = next((_text(buf, v) for h, v in meta if h == 2), "")
-            for h, v in meta:
-                st = dict(_fields(buf, *v)) if h == 5 else {}
-                if st.get(1) in hlo_ids and 6 in st:
-                    out[name] = _entry_scopes(buf, st[6])
-    return out
-
-
-def _scoped_ops(ops, programs, scopes: dict):
-    """(scopes, starts, ends) of the top-level ops ``ops`` that lie in a
-    ``kv.*`` scope of the program (``programs``: the chip's ``XLA
-    Modules`` names, starts, ends) running at their start."""
-    names, s, e = ops
-    p_names, p_starts, p_ends = programs
-    at = np.searchsorted(p_starts, s, side="right") - 1
-    out = ([], [], [])
-    for n, s0, e0, i in zip(names, s, e, at):
-        scope = (scopes.get(p_names[i], {}).get(n)
-                 if i >= 0 and s0 < p_ends[i] else None)
-        if scope is not None:
-            for col, x in zip(out, (scope, s0, e0)):
-                col.append(x)
-    return out[0], np.asarray(out[1], float), np.asarray(out[2], float)
-
-
 def load(xplane, peak: Optional[dict] = None, neighborhood: int = 8,
          val_words: int = 4) -> Traced:
     """Read an ``.xplane.pb``: the ``bench.*`` host spans, and of every
-    TPU chip its ``XLA Modules`` (programs run) and ``XLA Ops``; and the
-    program's own ``kv.*``/``host.*`` spans and scoped ops."""
+    TPU chip its ``XLA Modules`` (programs run) and ``XLA Ops``."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(str(xplane))
     spans, modules, ops, a2a = [], {}, {}, {}
-    program, programs, scoped = [], {}, {}
     for plane in data.planes:
         if plane.name.startswith(DEVICE_PLANE):
             chip = int(plane.name[len(DEVICE_PLANE):].split()[0])
             for line in plane.lines:
                 if line.name == MODULES_LINE:
-                    n, s, e, _, _ = _device_line(line, True)
+                    _, s, e, _, _ = _device_line(line, True)
                     modules[chip] = (s, e)
-                    programs[chip] = (n, s, e)
                 elif line.name == OPS_LINE:
                     n, s, e, ps, pe = _device_line(
                         line, False, lambda name: "all-to-all" in name)
@@ -430,16 +256,5 @@ def load(xplane, peak: Optional[dict] = None, neighborhood: int = 8,
                         spans.append(Span(ev.name, ev.start_ns,
                                           ev.start_ns + ev.duration_ns,
                                           _stats(ev)))
-                    elif ev.name.startswith(PROGRAM_PREFIXES):
-                        program.append(Span(ev.name, ev.start_ns,
-                                            ev.start_ns + ev.duration_ns,
-                                            _stats(ev)))
     spans.sort(key=lambda s: s.start)
-    program.sort(key=lambda s: s.start)
-    hlo = program_scopes(xplane) if ops else {}
-    for chip in ops:
-        found = _scoped_ops(ops[chip], programs.get(chip, ([], [], [])), hlo)
-        if found[0]:
-            scoped[chip] = found
-    return Traced(spans, modules, ops, a2a, peak, neighborhood, val_words,
-                  program, scoped)
+    return Traced(spans, modules, ops, a2a, peak, neighborhood, val_words)

@@ -1,8 +1,9 @@
 """The program's own spans, scopes and counters in the trace reduction:
-the loader keeps them beside the benchmark's spans, the readers that use
-them give known answers on a hand-made trace and nothing where the
-program does not trace itself, and the readers that were there before
-read the kept chip fixture as they did."""
+``bench/metrics/_program.py`` finds them in the window's trace beside the
+benchmark's spans, the readers that use them give known answers on a
+hand-made trace and nothing where the program does not trace itself, and
+the readers that were there before read the kept chip fixture as they
+did."""
 import gc
 import json
 import pathlib
@@ -15,6 +16,7 @@ ROOT = pathlib.Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
 from bench import harness, roofline, trace  # noqa: E402
+from bench.metrics import _program  # noqa: E402
 
 FIXTURES = pathlib.Path(__file__).resolve().parent / "fixtures"
 MS = 1e6                                          # ns
@@ -59,8 +61,9 @@ def _hand_made(chips=1):
                    "kv.set.scan", "kv.route", "kv.get.vm"],
                   f(14, 16, 40, 61, 62, 80, 95), f(16, 40, 42, 62, 80, 81, 98))
               for c in range(chips)}
-    return trace.Traced(spans, modules, ops, {}, roofline.peak("TPU v5 lite"),
-                        8, 4, program, scoped)
+    t = trace.Traced(spans, modules, ops, {}, roofline.peak("TPU v5 lite"),
+                     8, 4)
+    return _program.attach(t, _program.Program(program, scoped))
 
 
 @pytest.mark.parametrize("chips", [1, 4])
@@ -81,10 +84,12 @@ def test_calls_the_trace_holds_no_device_event_of_are_left_out():
     the device shows nothing): its idle time is the trace's gap, not a
     stall, and it has no route or VM time to divide."""
     t = _hand_made()
+    p = _program.of(t)
     modules = {0: (t.modules[0][0][:2], t.modules[0][1][:2])}
-    scoped = {0: tuple(x[:6] for x in t.scoped[0])}
-    blind = trace.Traced(t.spans, modules, t.ops, {}, t.peak, 8, 4,
-                         t.program, scoped)
+    scoped = {0: tuple(x[:6] for x in p.scoped[0])}
+    blind = _program.attach(
+        trace.Traced(t.spans, modules, t.ops, {}, t.peak, 8, 4),
+        _program.Program(p.spans, scoped))
     read = {m: harness.reader(m)(blind) for m in NEW}
     assert read["get_stall_ms"] == pytest.approx(37 - 28)
     assert read["get_route_ms"] == pytest.approx(4)
@@ -94,30 +99,45 @@ def test_calls_the_trace_holds_no_device_event_of_are_left_out():
 
 def test_program_fields_round_trip_through_json():
     t = _hand_made(2)
-    doc = json.loads(json.dumps(t.to_json()))
-    back = trace.Traced.from_json(doc, t.peak, 8, 4)
+    p = _program.of(t)
+    doc = json.loads(json.dumps({**t.to_json(), **p.to_json()}))
+    back = _program.attach(trace.Traced.from_json(doc, t.peak, 8, 4),
+                           _program.Program.from_json(doc))
     assert back.to_json() == t.to_json()
-    assert [s.name for s in back.program] == [s.name for s in t.program]
+    assert _program.of(back).to_json() == p.to_json()
+    assert [s.name for s in _program.of(back).spans] == \
+        [s.name for s in p.spans]
     assert {m: harness.reader(m)(back) for m in NEW} == \
         {m: harness.reader(m)(t) for m in NEW}
 
 
-def test_new_readers_find_nothing_where_the_program_does_not_trace():
+def test_new_readers_find_nothing_where_the_program_does_not_trace(
+        tmp_path, monkeypatch):
     """A trace of a program without spans, scopes or counters of its own
-    (as the benchmark's first commit traced it)."""
+    (as the benchmark's first commit traced it), and a window whose trace
+    is not on disk."""
+    monkeypatch.setattr(_program, "TRACE_DIR", tmp_path)
     t = _hand_made()
     bare = trace.Traced(t.spans, t.modules, t.ops, t.a2a, t.peak, 8, 4)
     for m in NEW:
         assert harness.reader(m)(bare) is None, m
+    assert _program.of(bare).spans == [] and _program.of(bare).scoped == {}
+    nothing = _program.attach(
+        trace.Traced(t.spans, t.modules, t.ops, t.a2a, t.peak, 8, 4),
+        _program.Program([], {}))
+    for m in NEW:
+        assert harness.reader(m)(nothing) is None, m
 
 
-def test_old_fixture_reads_as_before():
+def test_old_fixture_reads_as_before(tmp_path, monkeypatch):
     """The chip fixture kept from before the program traced itself loads
     unchanged: the readers that were there read the values they read
     then, and the new ones find nothing."""
+    monkeypatch.setattr(_program, "TRACE_DIR", tmp_path)
     doc = json.loads((FIXTURES / "ycsb-a.1chip.trace.json").read_text())
     t = trace.Traced.from_json(doc, roofline.peak("TPU v5 lite"), 8, 4)
-    assert t.program == [] and t.scoped == {}
+    p = _program.Program.from_json(doc)
+    assert p.spans == [] and p.scoped == {}
     want = {"device_idle_share": 11.51289228330129,
             "service_host_ms": 125.98608499999999,
             "get_device_ms": 1180.0856306666667,
@@ -135,15 +155,17 @@ def test_old_fixture_reads_as_before():
 def test_readers_on_a_trace_recorded_on_the_chip():
     """A 7 s window of a ``--trace 1`` run of ``ycsb-a.1chip`` on a TPU v5e
     (3 GET and 3 SET calls), with the program's spans, counters and
-    scoped ops, reduced by :func:`bench.trace.load` and kept in its
-    compact form."""
+    scoped ops, reduced by :func:`bench.trace.load` and
+    :func:`bench.metrics._program.load` and kept in their compact form."""
     doc = json.loads(
         (FIXTURES / "ycsb-a.1chip.program.trace.json").read_text())
     t = trace.Traced.from_json(doc, roofline.peak("TPU v5 lite"), 8, 7)
+    p = _program.Program.from_json(doc)
+    _program.attach(t, p)
     assert t.n_chips == 1 and 0 < t.busy_s() <= t.window_s
     assert len(t.calls("bench.get")) == 3 and len(t.calls("bench.set")) == 3
-    assert set(t.scoped[0][0]) == {"kv.route", "kv.get.vm", "kv.set.scan"}
-    assert [c["trips"] for c in t.counters("get").values()] == [49] * 3
+    assert set(p.scoped[0][0]) == {"kv.route", "kv.get.vm", "kv.set.scan"}
+    assert [c["trips"] for c in p.counters("get").values()] == [49] * 3
     read = {m: harness.reader(m)(t) for m in OLD + NEW}
     assert read["a2a_ms"] is None                 # one chip: no exchange
     assert all(read[m] is not None for m in NEW), read
@@ -157,15 +179,10 @@ def test_readers_on_a_trace_recorded_on_the_chip():
     assert 0 < read["kv_host_ms"] < read["service_host_ms"]
     assert read["get_stall_ms"] >= 0 and read["host_gc_ms"] >= 0
     assert trace.Traced.from_json(t.to_json()).to_json() == t.to_json()
+    assert _program.Program.from_json(p.to_json()).to_json() == p.to_json()
 
 
-def test_service_spans_reach_the_loader(tmp_path):
-    """Under a profiler trace on the CPU, a 2^10-bucket service's calls
-    leave ``kv.get_many``, ``kv.set_many``, ``kv.sync``, ``kv.counters``
-    and a forced collection's ``host.gc`` in ``Traced.program``, beside
-    the benchmark's own spans."""
-    import jax
-
+def _small_service():
     from repro.rdma import failure
 
     svc = failure.ShardedKVService.start(
@@ -176,11 +193,37 @@ def test_service_spans_reach_the_loader(tmp_path):
     sk = np.zeros((1, 8), np.int32)
     sk[0, :3] = [3, 500, 501]
     sv = np.ones((1, 8, 2), np.int32)
-    jax.device_get(svc.get_many(q).found)        # compiles outside the trace
-    jax.device_get(svc.set_many(sk, sv).applied)
-    tracer = trace.Tracer(tmp_path / "t")
+    return svc, q, sk, sv
+
+
+def _traced_window(path, calls) -> trace.Traced:
+    """Trace ``calls()`` inside a ``bench.window`` span as ``bench/run.py``
+    does, under ``path``, and reduce it with :func:`bench.trace.load`; the
+    trace stays on disk."""
+    import jax
+
+    tracer = trace.Tracer(path)
     tracer.start()
     with jax.profiler.TraceAnnotation("bench.window"):
+        calls()
+    tracer.stop()
+    return trace.load(tracer.xplane())
+
+
+def test_service_spans_reach_the_loader(tmp_path, monkeypatch):
+    """Under a profiler trace on the CPU, a 2^10-bucket service's calls
+    leave ``kv.get_many``, ``kv.set_many``, ``kv.sync``, ``kv.counters``
+    and a forced collection's ``host.gc`` in the window's
+    :class:`bench.metrics._program.Program`, found as a reader finds it,
+    beside the benchmark's own spans."""
+    import jax
+
+    monkeypatch.setattr(_program, "TRACE_DIR", tmp_path)
+    svc, q, sk, sv = _small_service()
+    jax.device_get(svc.get_many(q).found)        # compiles outside the trace
+    jax.device_get(svc.set_many(sk, sv).applied)
+
+    def calls():
         with jax.profiler.TraceAnnotation("bench.get", live=10):
             jax.device_get(svc.get_many(q).found)
         with jax.profiler.TraceAnnotation("bench.set", live=3):
@@ -188,27 +231,53 @@ def test_service_spans_reach_the_loader(tmp_path):
         gc.collect()
         with jax.profiler.TraceAnnotation("bench.get", live=10):
             jax.device_get(svc.get_many(q).found)
-    tracer.stop()
-    t = trace.load(tracer.xplane())
-    tracer.remove()
+
+    t = _traced_window(tmp_path / "cell", calls)
     assert [s.name for s in t.spans] == ["bench.window", "bench.get",
                                          "bench.set", "bench.get"]
-    names = {s.name for s in t.program}
+    p = _program.of(t)
+    names = {s.name for s in p.spans}
     assert {"kv.get_many", "kv.set_many", "kv.sync", "kv.counters",
             "host.gc"} <= names
-    gets = t.program_in("kv.get_many", *t.window)
-    sets = t.program_in("kv.set_many", *t.window)
+    gets = p.spans_in("kv.get_many", *t.window)
+    sets = p.spans_in("kv.set_many", *t.window)
     assert [s.args["width"] for s in gets] == [16, 16]
     assert sets[0].args["seq"] == gets[0].args["seq"] + 1
-    got = t.counters("get")[gets[0].args["seq"]]
+    got = p.counters("get")[gets[0].args["seq"]]
     assert got["trips"] > 0 and 0 < got["steps"] <= got["lanes"]
-    assert t.counters("set")[sets[0].args["seq"]]["scanned"] == 3
-    sync = t.program_in("kv.sync", sets[0].start, sets[0].end)
+    assert p.counters("set")[sets[0].args["seq"]]["scanned"] == 3
+    sync = p.spans_in("kv.sync", sets[0].start, sets[0].end)
     assert [s.args["what"] for s in sync] == ["status"]
     assert any(s.name == "host.gc" and s.args["generation"] == 2
-               for s in t.program)
+               for s in p.spans)
     assert harness.reader("kv_host_ms")(t) > 0
     assert harness.reader("get_vm_lane_share")(t) > 0
+
+
+def test_readers_find_the_trace_of_their_own_window(tmp_path, monkeypatch):
+    """Two traces on disk, as a run that was cut leaves one behind: each
+    window reads the program's spans of its own trace, the older one
+    included, and a window of neither trace reads nothing."""
+    import jax
+
+    monkeypatch.setattr(_program, "TRACE_DIR", tmp_path)
+    svc, q, _, _ = _small_service()
+    jax.device_get(svc.get_many(q).found)
+
+    def one_get():
+        with jax.profiler.TraceAnnotation("bench.get", live=10):
+            jax.device_get(svc.get_many(q).found)
+
+    old = _traced_window(tmp_path / "old", one_get)
+    new = _traced_window(tmp_path / "new", lambda: (one_get(), one_get()))
+    seqs = [[s.args["seq"] for s in _program.of(t).spans_in(
+        "kv.get_many", *t.window)] for t in (old, new)]
+    assert len(seqs[0]) == 1 and len(seqs[1]) == 2
+    assert seqs[1][0] > seqs[0][0]
+    other = trace.Traced([trace.Span("bench.window", 0.0, 1.0, {})],
+                         {}, {}, {})
+    assert _program.of(other).spans == []
+    assert harness.reader("kv_host_ms")(other) is None
 
 
 def _vint(x: int) -> bytes:
@@ -244,15 +313,17 @@ def test_scopes_of_top_level_ops_come_from_the_programs_hlo(tmp_path):
 
     module = jax.jit(body).lower(jnp.ones(8)).compile() \
         .runtime_executable().hlo_modules()[0]
-    stat = _int(1, 7) + _msg(6, _msg(1, module.as_serialized_hlo_module_proto()))
+    hlo = module.as_serialized_hlo_module_proto()
+    stat = _int(1, 7) + _msg(6, _msg(1, hlo))
     event = _int(1, 1) + _msg(2, b"jit_body(1)") + _msg(5, stat)
-    plane = (_msg(2, trace.METADATA_PLANE.encode())
+    plane = (_msg(2, _program.METADATA_PLANE.encode())
              + _msg(4, _int(1, 1) + _msg(2, event))
-             + _msg(5, _int(1, 7) + _msg(2, _int(1, 7)
-                                          + _msg(2, trace.HLO_STAT.encode()))))
+             + _msg(5, _int(1, 7)
+                    + _msg(2, _int(1, 7)
+                           + _msg(2, _program.HLO_STAT.encode()))))
     path = tmp_path / "t.xplane.pb"
     path.write_bytes(_msg(1, _msg(2, b"/host:CPU")) + _msg(1, plane))
-    scopes = trace.program_scopes(path)
+    scopes = _program.program_scopes(path)
     assert list(scopes) == ["jit_body(1)"]
     by_op = {op.split(".")[0]: s for op, s in scopes["jit_body(1)"].items()}
     assert by_op["sort"] == "kv.route" and by_op["while"] == "kv.get.vm"
@@ -262,5 +333,6 @@ def test_scopes_of_top_level_ops_come_from_the_programs_hlo(tmp_path):
            np.asarray([2.0, 4.0, 13.0]))
     programs = (["jit_body(1)", "jit_other(2)"], np.asarray([0.0, 10.0]),
                 np.asarray([5.0, 15.0]))
-    names, s, e = trace._scoped_ops(ops, programs, scopes)
-    assert names == ["kv.route"] and s.tolist() == [1.0] and e.tolist() == [2.0]
+    names, s, e = _program._scoped_ops(ops, programs, scopes)
+    assert names == ["kv.route"]
+    assert s.tolist() == [1.0] and e.tolist() == [2.0]
