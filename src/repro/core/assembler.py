@@ -38,6 +38,10 @@ class WRRef:
         return self.addr("ctrl")
 
 
+class SegmentError(ValueError):
+    """A declared read-only segment that the program could write."""
+
+
 class WQBuilder:
     def __init__(self, prog: "Program", index: int, base: int, size: int,
                  ordering: int, managed: bool, recycled: bool,
@@ -154,6 +158,7 @@ class Program:
         self._data_ptr = mem_words
         self._data_init: Dict[int, int] = {}
         self.symbols: Dict[str, int] = {}
+        self.segment: Optional[machine.Segment] = None
 
     # -- queues ---------------------------------------------------------------
     def add_wq(self, size: int, ordering: int = isa.ORD_WQ,
@@ -193,6 +198,43 @@ class Program:
                 f"MAX_SCATTER={isa.MAX_SCATTER}")
         return self.alloc(1 + len(dsts), [len(dsts)] + list(dsts))
 
+    def read_only(self, lo: int, hi: int) -> machine.Segment:
+        """Declare ``[lo, hi)`` one read-only segment, which a run may then
+        share between contexts (:func:`machine.run_segmented`).
+        :meth:`finalize` refuses the program if a code word lies in it or
+        a static write could land in it (:class:`SegmentError`)."""
+        self.segment = machine.Segment(lo, hi)
+        return self.segment
+
+    def _check_segment(self):
+        lo, hi = seg = self.segment
+        if not 0 <= lo < hi <= self.mem_words:
+            raise SegmentError(f"segment {seg} outside the "
+                               f"{self.mem_words}-word image")
+        if lo < self._code_top:
+            raise SegmentError(f"segment [{lo}, {hi}) holds code words "
+                               f"(the code region ends at {self._code_top})")
+
+        def refuse(a, n, what):
+            if a >= 0 and a < hi and a + n > lo:
+                raise SegmentError(
+                    f"segment [{lo}, {hi}) is written by {what} "
+                    f"(words [{a}, {a + n}))")
+
+        for wq in self.wqs:
+            for slot, wr in enumerate(wq.wrs):
+                name = f"WQ{wq.index}[{slot}] {wr['tag'] or ''}".rstrip()
+                # any verb may be converted into a copy of its ``ln`` words
+                refuse(wr["dst"], max(1, min(wr["ln"], isa.MAX_COPY)),
+                       f"{name}'s dst")
+                if wr["opcode"] in (isa.CAS, isa.ADD):
+                    refuse(wr["src"], 1, f"{name}'s return-old")
+                if wr["opcode"] == isa.RECV and wr["aux"] >= 0:
+                    n = self._data_init.get(wr["aux"], 0)
+                    for i in range(n):
+                        refuse(self._data_init.get(wr["aux"] + 1 + i, 0), 1,
+                               f"{name}'s scatter entry {i}")
+
     # -- finalize ---------------------------------------------------------------
     def finalize(self, verify: bool = False, waivers: Sequence = (),
                  name: str = "program") -> Tuple[machine.MachineSpec,
@@ -214,6 +256,8 @@ class Program:
             raise ValueError(
                 f"code ({self._code_top}) collides with data "
                 f"({self._data_ptr}); grow mem_words")
+        if self.segment is not None:
+            self._check_segment()
         img = np.zeros(self.mem_words, dtype=np.int32)
         for wq in self.wqs:
             for slot, wr in enumerate(wq.wrs):

@@ -57,6 +57,15 @@ def _run_many(spec, state, wq, payloads, max_steps, faults=None):
     return machine.run_batch(spec, batch, max_steps, faults)
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 4, 6))
+def _run_many_segmented(spec, segment, state, words, wq, payloads, max_steps):
+    batch = machine.deliver_many(state, wq, payloads)
+    batch = batch._replace(steps=jnp.zeros_like(batch.steps))
+    return jax.vmap(
+        lambda s, w: machine.run_segmented(spec, segment, s, w, max_steps),
+        in_axes=(0, None))(batch, words)
+
+
 @functools.partial(jax.jit, static_argnums=(0, 2, 4, 5, 6))
 def _serve_stream(spec, state, wq, payloads, resp, resp_len, max_steps,
                   faults=None):
@@ -272,6 +281,23 @@ class ChainEngine:
         batch = machine.deliver_many(state, wq, pays)
         batch = batch._replace(steps=jnp.zeros_like(batch.steps))
         return self._run_batch_pallas(batch, max_steps, faults)
+
+    def run_many_segmented(self, state: machine.VMState,
+                           segment: machine.Segment, words, wq: int,
+                           payloads, max_steps: int = 4096):
+        """:meth:`run_many` over a split image: ``state`` holds the image
+        outside ``segment`` (:func:`machine.split_image`) and ``words`` the
+        segment's words, which all N contexts read from one array.
+        Returns ``(batched state, breach (N,) bool)``: a context that
+        stored into the segment halted there (:func:`machine.run_segmented`).
+        Interpreter only."""
+        if self.backend not in _INTERP_BACKENDS:
+            raise ValueError(
+                "a segmented run shares one read-only segment between "
+                "contexts; the pallas kernel copies each context's whole "
+                "image — use the interp backend")
+        return _run_many_segmented(self.spec, segment, state, words, wq,
+                                   _pad_payloads(payloads), max_steps)
 
     def serve_stream(self, state: machine.VMState, wq: int, payloads,
                      resp_region: int, resp_len: int,

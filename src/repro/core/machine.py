@@ -58,6 +58,23 @@ class MachineSpec(NamedTuple):
         return len(self.wq_bases)
 
 
+class Segment(NamedTuple):
+    """A read-only segment ``[lo, hi)`` of a program's address space.
+
+    A run with a segment splits the image in two: the segment's words in
+    one array that every context shares, and each context's *private*
+    image holding every other word, word ``a >= hi`` at ``a - (hi - lo)``
+    (see :func:`run_segmented`).  The program declares it
+    (:meth:`repro.core.assembler.Program.read_only`), which refuses any
+    static write into it; the code region lies below ``lo``."""
+    lo: int
+    hi: int
+
+    @property
+    def width(self) -> int:
+        return self.hi - self.lo
+
+
 class VMState(NamedTuple):
     """Dynamic machine state — a pytree of arrays (vmap-able)."""
     mem: jnp.ndarray            # i32[mem_words + MAX_COPY guard]
@@ -110,6 +127,18 @@ def init_state(spec: MachineSpec, mem_image: np.ndarray,
     # refused inside a shard_map over a mesh of another size.
     with jax.ensure_compile_time_eval():
         return jax.tree_util.tree_map(jnp.asarray, host)
+
+
+def split_image(state: VMState, segment: Segment):
+    """A whole-image state (batched or not) as ``(private state, segment
+    words)``: the image outside ``segment``, and the segment's words.
+    Host data, like :func:`init_state`: ``state`` must be concrete."""
+    mem = np.asarray(state.mem)
+    private = np.concatenate([mem[..., :segment.lo], mem[..., segment.hi:]],
+                             axis=-1)
+    with jax.ensure_compile_time_eval():
+        return (state._replace(mem=jnp.asarray(private)),
+                jnp.asarray(mem[..., segment.lo:segment.hi]))
 
 
 # ---------------------------------------------------------------------------
@@ -190,8 +219,116 @@ def _maybe_store(mem, addr, value):
     return mem.at[safe].set(jnp.where(addr >= 0, value, cur))
 
 
+def _recv_scatter(mem, a, n, payload):
+    """RECV: mem[table[i]] = payload[i] for i < n, table at ``a + 1``."""
+    def scatter(i, m):
+        sd = jnp.maximum(m[a + 1 + i], 0)
+        return m.at[sd].set(jnp.where(i < n, payload[i], m[sd]))
+
+    return lax.fori_loop(0, isa.MAX_SCATTER, scatter, mem)
+
+
+class _WholeImage:
+    """Data access of a run whose ``mem`` is the whole image."""
+    breach = None
+
+    @staticmethod
+    def load(mem, a):
+        return mem[a]
+
+    @staticmethod
+    def read(mem, a, n):
+        return lax.dynamic_slice(mem, (a,), (n,))
+
+    @staticmethod
+    def copy(mem, src, dst, ln):
+        return _masked_copy(mem, src, dst, ln)
+
+    @staticmethod
+    def store(mem, d, value, opcode):
+        return mem.at[d].set(value)
+
+    @staticmethod
+    def store_old(mem, addr, value):
+        return _maybe_store(mem, addr, value)
+
+    @staticmethod
+    def scatter(mem, a, n, payload):
+        return _recv_scatter(mem, a, n, payload)
+
+
+_RMW = (isa.WRITE_IMM, isa.CAS, isa.ADD, isa.MAX, isa.MIN)
+
+
+class _SplitImage:
+    """Data access of a run whose ``mem`` is the private image and whose
+    segment words are the one shared array ``words`` (:class:`Segment`).
+
+    Addresses stay the whole image's: a load of a word in the segment
+    reads ``words``, any other word the private image, and a block read
+    that straddles an edge selects word by word.  Clamps are the whole
+    image's too, so every answer is the one the whole image gives.
+    Stores go to the private image only: a store into the segment is
+    dropped and raises :attr:`breach`, which halts the context."""
+
+    def __init__(self, segment: Segment, words, image_words: int):
+        self.lo, self.hi = segment
+        self.words = words
+        self.image_words = image_words      # whole image, guard included
+        self.breach = jnp.zeros((), jnp.bool_)
+
+    def _inside(self, a):
+        return (a >= self.lo) & (a < self.hi)
+
+    def _private(self, a):
+        return jnp.where(a < self.lo, a, a - (self.hi - self.lo))
+
+    def load(self, mem, a):
+        shared = self.words[jnp.clip(a - self.lo, 0, self.hi - self.lo - 1)]
+        return jnp.where(self._inside(a), shared, mem[self._private(a)])
+
+    def read(self, mem, a, n):
+        start = jnp.clip(a, 0, self.image_words - n)   # dynamic_slice's clamp
+        return self.load(mem, start + jnp.arange(n, dtype=jnp.int32))
+
+    def _put(self, mem, a, value, writes):
+        inside = self._inside(a)
+        self.breach = self.breach | jnp.any(writes & inside)
+        drop = mem.shape[-1]                 # out of range: scatter drops it
+        idx = jnp.where(writes & ~inside, self._private(a), drop)
+        return mem.at[idx].set(value, mode="drop")
+
+    def copy(self, mem, src, dst, ln):
+        ln = jnp.clip(ln, 0, isa.MAX_COPY)
+        blk = self.read(mem, src, isa.MAX_COPY)
+        lanes = jnp.arange(isa.MAX_COPY, dtype=jnp.int32)
+        at = jnp.clip(dst, 0, self.image_words - isa.MAX_COPY) + lanes
+        return self._put(mem, at, blk, lanes < ln)
+
+    def store(self, mem, d, value, opcode):
+        # the other verbs write back what they read: no store at all
+        return self._put(mem, d, value, jnp.isin(opcode, jnp.asarray(_RMW)))
+
+    def store_old(self, mem, addr, value):
+        return self._put(mem, addr, value, addr >= 0)
+
+    def scatter(self, mem, a, n, payload):
+        def scatter(i, carry):
+            m, breach = carry
+            sd = jnp.maximum(self.load(m, a + 1 + i), 0)
+            inside = self._inside(sd)
+            idx = jnp.where((i < n) & ~inside, self._private(sd),
+                            m.shape[-1])
+            return (m.at[idx].set(payload[i], mode="drop"),
+                    breach | ((i < n) & inside))
+
+        mem, self.breach = lax.fori_loop(0, isa.MAX_SCATTER, scatter,
+                                         (mem, self.breach))
+        return mem
+
+
 @functools.lru_cache(maxsize=None)
-def _fused_step(spec: MachineSpec):
+def _fused_step(spec: MachineSpec, segment: Segment | None = None):
     """Spec-specialized (eligibility, execute) pair.
 
     All static lookup tables — WQ geometry, ordering modes, and the cost
@@ -200,7 +337,21 @@ def _fused_step(spec: MachineSpec):
     already computed for exactly the state it steps, so the fused ``run``
     evaluates eligibility once per iteration (the old cond/body split
     evaluated it twice).
+
+    With a ``segment`` the pair steps a split image (:func:`run_segmented`):
+    ``execute`` then takes the segment's ``words`` and returns ``(state,
+    breach)``.  WR fetches read the private image at their own address,
+    which is the translation of every code address: the code region lies
+    below ``segment.lo``.  Without one, the step is the whole-image code.
     """
+    if segment is not None:
+        code_top = max(b + n * isa.WR_WORDS
+                       for b, n in zip(spec.wq_bases, spec.wq_sizes))
+        if not code_top <= segment.lo < segment.hi <= spec.mem_words:
+            raise ValueError(
+                f"segment [{segment.lo}, {segment.hi}) must lie above the "
+                f"code region (words < {code_top}) and inside the "
+                f"{spec.mem_words}-word image")
     # numpy (not jnp) constants: they embed as trace-local constants in any
     # jit/vmap context without leaking tracers across the lru_cache.
     bases = np.asarray(spec.wq_bases, np.int32)
@@ -233,7 +384,7 @@ def _fused_step(spec: MachineSpec):
         return eligible, addr, opcode
 
     def execute(s: VMState, eligible, addrs, guard: bool = True,
-                faults=None, fault_counts=None):
+                faults=None, fault_counts=None, words=None):
         """One scheduling step.  With ``faults`` (a scalar-leaf
         ``repro.core.faults.FaultPlan``) the step also applies the armed
         fault semantics — WR suppression at a step index, spurious CAS
@@ -244,6 +395,8 @@ def _fused_step(spec: MachineSpec):
         ``(new_state, new_counts)`` instead of just the state.
         (``kill_step`` is a loop-condition fault — see :func:`run` — not
         a per-step one.)"""
+        port = (_WholeImage if segment is None else
+                _SplitImage(segment, words, spec.mem_words + GUARD_WORDS))
         w = jnp.argmin(jnp.where(eligible, s.clock, jnp.inf)).astype(
             jnp.int32)
 
@@ -287,11 +440,11 @@ def _fused_step(spec: MachineSpec):
         # bit-identical to the branch dispatch.
         is_copy = ((opcode == isa.WRITE) | (opcode == isa.READ)
                    | ((opcode == isa.SEND) & (opb < 0)))
-        mem = _masked_copy(s.mem, src, dst, jnp.where(is_copy, ln, 0))
+        mem = port.copy(s.mem, src, dst, jnp.where(is_copy, ln, 0))
 
         # scalar RMW store (identity `old` write when the verb has none)
         d = jnp.maximum(dst, 0)
-        old = mem[d]
+        old = port.load(mem, d)
         sval = old
         sval = jnp.where(opcode == isa.WRITE_IMM, opa, sval)
         cas_hit = old == opa
@@ -304,12 +457,12 @@ def _fused_step(spec: MachineSpec):
         sval = jnp.where(opcode == isa.ADD, old + opa, sval)
         sval = jnp.where(opcode == isa.MAX, jnp.maximum(old, opa), sval)
         sval = jnp.where(opcode == isa.MIN, jnp.minimum(old, opa), sval)
-        mem = mem.at[d].set(sval)
+        mem = port.store(mem, d, sval, opcode)
 
         # atomics' return-old path
         ret_addr = jnp.where(
             (opcode == isa.CAS) | (opcode == isa.ADD), src, -1)
-        mem = _maybe_store(mem, ret_addr, old)
+        mem = port.store_old(mem, ret_addr, old)
 
         # RECV: scatter the head message through the table at `aux`
         is_recv = opcode == isa.RECV
@@ -317,20 +470,13 @@ def _fused_step(spec: MachineSpec):
         rpayload = s.msg_buf[w, rslot]
         a = jnp.maximum(aux, 0)
         n_scatter = jnp.where(
-            is_recv, jnp.clip(mem[a], 0, isa.MAX_SCATTER), 0)
-
-        def scatter(i, m):
-            sd = jnp.maximum(m[a + 1 + i], 0)
-            return m.at[sd].set(
-                jnp.where(i < n_scatter, rpayload[i], m[sd]))
-
-        mem = lax.fori_loop(0, isa.MAX_SCATTER, scatter, mem)
+            is_recv, jnp.clip(port.load(mem, a), 0, isa.MAX_SCATTER), 0)
+        mem = port.scatter(mem, a, n_scatter, rpayload)
 
         # SEND to a peer QP (opb >= 0): enqueue payload on its msg queue.
         # The GUARD_WORDS pad makes this gather a plain dynamic_slice.
         send_msg = (opcode == isa.SEND) & (opb >= 0)
-        payload = lax.dynamic_slice(
-            s.mem, (jnp.maximum(src, 0),), (isa.MSG_WORDS,))
+        payload = port.read(s.mem, jnp.maximum(src, 0), isa.MSG_WORDS)
         mslot = s.msg_tail[tgt] % s.msg_buf.shape[1]
         msg_buf = s.msg_buf.at[tgt, mslot].set(
             jnp.where(send_msg, payload, s.msg_buf[tgt, mslot]))
@@ -349,6 +495,8 @@ def _fused_step(spec: MachineSpec):
             en_raises,
             jnp.maximum(s.enable_limit[tgt], opa), s.enable_limit[tgt]))
         halted = s.halted | (opcode == isa.HALT)
+        if port.breach is not None:
+            halted = halted | port.breach
 
         new = s._replace(mem=mem, msg_buf=msg_buf, msg_tail=msg_tail,
                          msg_head=msg_head, responses=responses,
@@ -385,6 +533,8 @@ def _fused_step(spec: MachineSpec):
             steps=new.steps + 1,
             verb_counts=new.verb_counts.at[opcode].add(1),
         )
+        if port.breach is not None:
+            return new, port.breach
         # if nothing was eligible, this step is a no-op; only the fields a
         # step can touch are selected — `tail` is host-owned and never
         # written.  The fused `run` skips the guard entirely: its cond
@@ -494,6 +644,46 @@ def run(spec: MachineSpec, state: VMState, max_steps: int = 4096,
     out, _, _, _ = lax.while_loop(
         cond, body, (state, elig0, addrs0, (zero, zero)))
     return out
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 4))
+def run_segmented(spec: MachineSpec, segment: Segment, state: VMState,
+                  words: jnp.ndarray, max_steps: int = 4096):
+    """:func:`run` over a split image: ``state.mem`` is the private image
+    (every word outside ``segment``, the guard pad included) and ``words``
+    the segment's ``(hi - lo,)`` words, read-only.
+
+    Under ``vmap`` give ``words`` ``in_axes=None``: it is then one array
+    that every context reads and the loop never carries, while each
+    context carries only its private image.  Every run ends as the same
+    program's :func:`run` over the whole image ends, except that a store
+    into the segment (only a patched address can aim one there) is
+    dropped and halts the context.  Returns ``(state, breach)``, breach
+    True iff that happened.
+    """
+    private = spec.mem_words + GUARD_WORDS - segment.width
+    if state.mem.shape[-1] != private or words.shape != (segment.width,):
+        raise ValueError(
+            f"split image of {state.mem.shape[-1]} private and "
+            f"{words.shape} segment words; segment [{segment.lo}, "
+            f"{segment.hi}) of this spec needs {private} and "
+            f"({segment.width},)")
+    eligibility, execute = _fused_step(spec, segment)
+
+    def cond(carry):
+        s, eligible, _, _ = carry
+        return jnp.any(eligible) & (~s.halted) & (s.steps < max_steps)
+
+    def body(carry):
+        s, eligible, addrs, breach = carry
+        new, bad = execute(s, eligible, addrs, guard=False, words=words)
+        e2, a2, _ = eligibility(new)
+        return new, e2, a2, breach | bad
+
+    elig0, addrs0, _ = eligibility(state)
+    out, _, _, breach = lax.while_loop(
+        cond, body, (state, elig0, addrs0, jnp.zeros((), jnp.bool_)))
+    return out, breach
 
 
 def run_batch(spec: MachineSpec, states: VMState,

@@ -301,6 +301,17 @@ class HopscotchShardServer:
     quiesces exactly like a miss (no response write), bit-exact with
     :func:`repro.kvstore.hopscotch.lookup_ttl`; the deadline is compared
     on device, not by the host.
+
+    **Shared segment.**  The chain never writes the table or the value
+    rows: every write lands in code, the response region, the scatter
+    table or the TTL ``e`` cells, at addresses fixed when the program is
+    built.  The program declares the table plus the value rows, ``[table_base,
+    resp_region)``, one read-only :class:`machine.Segment`, and
+    :meth:`device_segment` builds the split form of :meth:`device_state`:
+    one copy of those rows that every context of a batch reads, and a
+    private image of about a thousand words per context
+    (:meth:`ChainEngine.run_many_segmented`).  ``device_state``,
+    ``state0`` and :meth:`get_many` stay the whole-image oracle.
     """
     prog: Program
     spec: machine.MachineSpec
@@ -313,6 +324,8 @@ class HopscotchShardServer:
     resp_region: int
     recv_wq: int
     ttl: bool = False
+    segment: Optional[machine.Segment] = None
+    private0: Optional[machine.VMState] = None   # state0 outside the segment
 
     @property
     def resp_words(self) -> int:
@@ -353,6 +366,32 @@ class HopscotchShardServer:
         mem = mem.at[vidx.reshape(-1)].set(
             vals.astype(jnp.int32).reshape(-1))
         return self.state0._replace(mem=mem)
+
+    @property
+    def private_resp_region(self) -> int:
+        """The response region's address in the private image."""
+        return self.resp_region - self.segment.width
+
+    def device_segment(self, keys: jnp.ndarray, vals: jnp.ndarray,
+                       exp: Optional[jnp.ndarray] = None):
+        """:meth:`device_state` split at :attr:`segment`: ``(private state,
+        segment words)``, built without a scatter.  The segment is the
+        table rows ``[key, pad | deadline, val_ptr]`` followed by the
+        value rows ``[keys != EMPTY, v...]``; the private state is the
+        same for every shard."""
+        if self.ttl != (exp is not None):
+            raise ValueError(
+                "exp column required iff the server was built with "
+                f"ttl=True (ttl={self.ttl}, exp given={exp is not None})")
+        keys = keys.astype(jnp.int32)
+        rows = jnp.arange(self.n_buckets, dtype=jnp.int32)
+        pad = jnp.zeros_like(keys) if exp is None else exp.astype(jnp.int32)
+        val_ptr = self.values_base + rows * (self.val_len + 1)
+        table = jnp.stack([keys, pad, val_ptr], axis=1).reshape(-1)
+        values = jnp.concatenate(
+            [(keys != EMPTY_KEY).astype(jnp.int32)[:, None],
+             vals.astype(jnp.int32)], axis=1).reshape(-1)
+        return self.private0, jnp.concatenate([table, values])
 
     def device_payloads(self, queries: jnp.ndarray, home: jnp.ndarray,
                         now=None) -> jnp.ndarray:
@@ -534,12 +573,16 @@ def build_hopscotch_server(n_buckets: int, val_len: int,
     tbl = p.scatter_table(
         ([key_w, negnow_w] if ttl else cas_opa_addrs) + read_src_addrs)
     rq.recv(scatter_table=tbl, tag="hs.recv")
+    # the table and the value rows, contiguous below the response region
+    segment = p.read_only(table, values + n_buckets * row_stride)
 
     spec, st0 = p.finalize()
+    private0, _ = machine.split_image(st0, segment)
     return HopscotchShardServer(
         prog=p, spec=spec, state0=st0, n_buckets=n_buckets, val_len=val_len,
         neighborhood=neighborhood, table_base=table, values_base=values,
-        resp_region=resp, recv_wq=rq.index, ttl=ttl)
+        resp_region=resp, recv_wq=rq.index, ttl=ttl, segment=segment,
+        private0=private0)
 
 
 # ---------------------------------------------------------------------------

@@ -3,8 +3,10 @@
 * ``redn``      — §5.2: the request is routed to the owner shard, the
                   *offload chain* — an actual chain VM program
                   (:class:`repro.core.programs.HopscotchShardServer`,
-                  executed by ``ChainEngine.run_many``) — runs there, the
-                  value comes back: **1 RTT**, no host involvement.
+                  executed by ``ChainEngine.run_many_segmented``, every
+                  context reading the shard from one shared read-only
+                  segment) — runs there, the value comes back: **1 RTT**,
+                  no host involvement.
 * ``one_sided`` — FaRM/Pilaf style: RDMA READ of the H-bucket neighborhood
                   metadata, client-side match, RDMA READ of the value:
                   **2 RTTs**, no host involvement, 6x metadata overhead
@@ -172,13 +174,19 @@ class GetResult(NamedTuple):
     (admission), *not* a miss.  ``vm_steps`` (steady-state chain paths
     only) counts the WRs each chain-VM context of the owner's receive
     window executed, padded slots included; the vmapped VM loop runs as
-    many trips as an owner's largest count."""
+    many trips as an owner's largest count.  ``image_words`` (the same
+    paths) is the words of image each of those contexts carried.
+    ``breached`` (chain paths) counts the requests whose chain stored
+    into the shard's read-only segment: a fault of the program, never an
+    answer, so those rows are ``ok`` False and counted nowhere else."""
     found: jnp.ndarray      # (S, B) bool
     values: jnp.ndarray     # (S, B, V) int32
     ok: jnp.ndarray         # (S, B) bool — response authoritative
     dropped: jnp.ndarray    # (S,) int32 — capacity drops at the source
     deferred: jnp.ndarray   # (S,) int32 — admission-deferred at the source
     vm_steps: Optional[jnp.ndarray] = None  # (S, S * capacity) int32
+    image_words: Optional[jnp.ndarray] = None   # (S,) int32
+    breached: Optional[jnp.ndarray] = None      # (S,) int32
 
     def __repr__(self):
         # summarized, not the raw-array tuple dump — results show up in
@@ -188,10 +196,13 @@ class GetResult(NamedTuple):
             return (f"GetResult(traced: found={self.found}, "
                     f"ok={self.ok})")
         found, ok = np.asarray(self.found), np.asarray(self.ok)
+        breached = (0 if self.breached is None
+                    else int(np.asarray(self.breached).sum()))
         return (f"GetResult(found {int(found.sum())}/{found.size}, "
                 f"ok {int(ok.sum())}/{ok.size}, "
                 f"dropped={int(np.asarray(self.dropped).sum())}, "
-                f"deferred={int(np.asarray(self.deferred).sum())})")
+                f"deferred={int(np.asarray(self.deferred).sum())}"
+                f"{f', breached={breached}' if breached else ''})")
 
 
 def serving_mesh(n_shards: int, axis: str = "kv") -> Mesh:
@@ -260,20 +271,39 @@ class ShardedKV:
 # the three get paths (shard_map bodies; local table slice has leading dim 1)
 # ---------------------------------------------------------------------------
 
+def _chain_get(srv, keys, vals, exp, q, home, now, live, *, n_shards,
+               capacity, axis):
+    """One GET stage of the chain server ``srv`` at the owner: the shard
+    split at the server's read-only segment, so the window's contexts
+    share one copy of the table and value rows.  Returns ``(resp, ok,
+    steps, breached, image_words)``, the last the words each context
+    carried."""
+    private, words = srv.device_segment(keys, vals, exp)
+    payload = srv.device_payloads(q, home, now)
+    resp, ok, steps, breached = transport.triggered_chain_engine(
+        srv.engine, private, srv.segment, words, srv.recv_wq,
+        srv.private_resp_region, srv.resp_words, payload,
+        shard_of(q, n_shards), n_shards, capacity, axis, live)
+    return resp, ok, steps, breached, private.mem.shape[-1]
+
+
+def _chain_get_outputs(resp, ok, steps, breached, image_words):
+    return ((resp[:, 0] > 0)[None], resp[None, :, 1:], ok[None],
+            steps[None], jnp.full((1,), image_words, jnp.int32),
+            breached[None])
+
+
 def _redn_get_local(keys, vals, queries, live, *, n_shards, capacity, axis,
                     neighborhood, val_words):
     """RedN path: the pre-posted chain VM program executes at the owner —
     1 RTT, the hash probing done by verbs, not the host."""
     q = queries.reshape(-1)
-    dest = shard_of(q, n_shards)
     n_buckets = keys.shape[1]
     srv = programs.build_hopscotch_server(n_buckets, val_words, neighborhood)
-    state = srv.device_state(keys[0], vals[0])
-    payload = srv.device_payloads(q, hopscotch.bucket_of(q, n_buckets))
-    resp, ok, steps = transport.triggered_chain_engine(
-        srv.engine, state, srv.recv_wq, srv.resp_region, srv.resp_words,
-        payload, dest, n_shards, capacity, axis, live.reshape(-1))
-    return (resp[:, 0] > 0)[None], resp[None, :, 1:], ok[None], steps[None]
+    return _chain_get_outputs(*_chain_get(
+        srv, keys[0], vals[0], None, q, hopscotch.bucket_of(q, n_buckets),
+        None, live.reshape(-1), n_shards=n_shards, capacity=capacity,
+        axis=axis))
 
 
 def _redn_get_ttl_local(keys, vals, exp, now, queries, live, *, n_shards,
@@ -284,17 +314,13 @@ def _redn_get_ttl_local(keys, vals, exp, now, queries, live, *, n_shards,
     quiesces exactly like a miss, with the deadline compared on device
     (bit-exact with :func:`repro.kvstore.hopscotch.lookup_ttl`)."""
     q = queries.reshape(-1)
-    dest = shard_of(q, n_shards)
     n_buckets = keys.shape[1]
     srv = programs.build_hopscotch_server(n_buckets, val_words,
                                           neighborhood, ttl=True)
-    state = srv.device_state(keys[0], vals[0], exp[0])
-    payload = srv.device_payloads(q, hopscotch.bucket_of(q, n_buckets),
-                                  now[0])
-    resp, ok, steps = transport.triggered_chain_engine(
-        srv.engine, state, srv.recv_wq, srv.resp_region, srv.resp_words,
-        payload, dest, n_shards, capacity, axis, live.reshape(-1))
-    return (resp[:, 0] > 0)[None], resp[None, :, 1:], ok[None], steps[None]
+    return _chain_get_outputs(*_chain_get(
+        srv, keys[0], vals[0], exp[0], q, hopscotch.bucket_of(q, n_buckets),
+        now[0], live.reshape(-1), n_shards=n_shards, capacity=capacity,
+        axis=axis))
 
 
 def _one_sided_get_local(keys, vals, queries, live, *, n_shards, capacity,
@@ -531,6 +557,15 @@ def _mesh_fingerprint(mesh: Mesh):
             tuple((d.platform, d.id) for d in mesh.devices.flat))
 
 
+def _get_counts(live, ok, lost=0):
+    """A GET body's per-shard ``(dropped, deferred)``; ``lost`` rows were
+    dispatched but breached (:class:`GetResult`), so they are no drops."""
+    deferred = jnp.sum(~live, dtype=jnp.int32).reshape(1)
+    dropped = (jnp.sum(live, dtype=jnp.int32)
+               - jnp.sum(ok, dtype=jnp.int32) - lost).reshape(1)
+    return dropped, deferred
+
+
 def _mapped_get(mesh: Mesh, axis: str, method: str, n_shards: int,
                 capacity: int, neighborhood: int, val_words: int):
     """Compile-cache the sharded get per (mesh geometry, path geometry):
@@ -548,15 +583,17 @@ def _mapped_get(mesh: Mesh, axis: str, method: str, n_shards: int,
         neighborhood=neighborhood, val_words=val_words)
 
     def body(keys, vals, queries, live):
-        # the chain path also returns its contexts' VM steps
-        found, v, ok, *steps = path(keys, vals, queries, live)
-        deferred = jnp.sum(~live, dtype=jnp.int32).reshape(1)
-        dropped = (jnp.sum(live, dtype=jnp.int32)
-                   - jnp.sum(ok, dtype=jnp.int32)).reshape(1)
-        return (found, v, ok, dropped, deferred, *steps)
+        found, v, ok, *chain = path(keys, vals, queries, live)
+        if not chain:
+            return (found, v, ok, *_get_counts(live, ok))
+        # the chain path: its contexts' VM steps, image words, breaches
+        steps, image_words, breached = chain
+        lost = jnp.sum(breached, dtype=jnp.int32).reshape(1)
+        return (found, v, ok, *_get_counts(live, ok, lost), steps,
+                image_words, lost)
 
     spec = P(axis)
-    n_out = 6 if method == "redn" else 5
+    n_out = 8 if method == "redn" else 5
     fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec, spec),
         out_specs=(spec,) * n_out, check_vma=False))
@@ -578,15 +615,15 @@ def _mapped_get_ttl(mesh: Mesh, axis: str, n_shards: int, capacity: int,
         axis=axis, neighborhood=neighborhood, val_words=val_words)
 
     def body(keys, vals, exp, nows, queries, live):
-        found, v, ok, steps = path(keys, vals, exp, nows, queries, live)
-        deferred = jnp.sum(~live, dtype=jnp.int32).reshape(1)
-        dropped = (jnp.sum(live, dtype=jnp.int32)
-                   - jnp.sum(ok, dtype=jnp.int32)).reshape(1)
-        return found, v, ok, dropped, deferred, steps
+        found, v, ok, steps, image_words, breached = path(
+            keys, vals, exp, nows, queries, live)
+        lost = jnp.sum(breached, dtype=jnp.int32).reshape(1)
+        return (found, v, ok, *_get_counts(live, ok, lost), steps,
+                image_words, lost)
 
     spec = P(axis)
     fn = jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(spec,) * 6, out_specs=(spec,) * 6,
+        body, mesh=mesh, in_specs=(spec,) * 6, out_specs=(spec,) * 8,
         check_vma=False))
     return _mapped_cache_put(key, fn)
 
@@ -1642,14 +1679,13 @@ def _mig_get_local(ok, ov, nk, nv, wm, queries, live, *, n_shards,
     dest = shard_of(q, n_shards)
     lv = live.reshape(-1)
     n = ok.shape[1]
+    stage = functools.partial(_chain_get, n_shards=n_shards,
+                              capacity=capacity, axis=axis)
 
     srv_new = programs.build_hopscotch_server(2 * n, val_words,
                                               neighborhood)
-    st_new = srv_new.device_state(nk[0], nv[0])
-    pay_new = srv_new.device_payloads(q, hopscotch.bucket_of(q, 2 * n))
-    resp1, ok1, _ = transport.triggered_chain_engine(
-        srv_new.engine, st_new, srv_new.recv_wq, srv_new.resp_region,
-        srv_new.resp_words, pay_new, dest, n_shards, capacity, axis, lv)
+    resp1, ok1, _, bad1, _ = stage(srv_new, nk[0], nv[0], None, q,
+                                   hopscotch.bucket_of(q, 2 * n), None, lv)
     found1 = resp1[:, 0] > 0
 
     wms = jax.lax.all_gather(wm, axis).reshape(-1)      # (S,) watermarks
@@ -1660,16 +1696,14 @@ def _mig_get_local(ok, ov, nk, nv, wm, queries, live, *, n_shards,
     live2 = lv & ok1 & ~found1 & ~mig_done
 
     srv_old = programs.build_hopscotch_server(n, val_words, neighborhood)
-    st_old = srv_old.device_state(ok[0], ov[0])
-    pay_old = srv_old.device_payloads(q, h_old)
-    resp2, _, _ = transport.triggered_chain_engine(
-        srv_old.engine, st_old, srv_old.recv_wq, srv_old.resp_region,
-        srv_old.resp_words, pay_old, dest, n_shards, capacity, axis, live2)
+    resp2, _, _, bad2, _ = stage(srv_old, ok[0], ov[0], None, q, h_old,
+                                 None, live2)
     found2 = resp2[:, 0] > 0
 
     found = found1 | found2
     vals = jnp.where(found1[:, None], resp1[:, 1:], resp2[:, 1:])
-    return found[None], vals[None], ok1[None]
+    breached = bad1 | bad2
+    return found[None], vals[None], (ok1 & ~bad2)[None], breached[None]
 
 
 def sharded_get_migrating(mesh: Mesh, axis: str, rs: ResizeState,
@@ -1714,8 +1748,9 @@ def _get_resize(mesh: Mesh, axis: str, rs: ResizeState,
             deferred=jnp.sum(~live, axis=1, dtype=jnp.int32))
     mapped = _mapped_mig_get(mesh, axis, n_shards, capacity, neighborhood,
                              rs.vals.shape[-1])
-    return GetResult(*mapped(rs.keys, rs.vals, rs.new_keys, rs.new_vals,
-                             rs.watermark, queries, live))
+    *res, breached = mapped(rs.keys, rs.vals, rs.new_keys, rs.new_vals,
+                            rs.watermark, queries, live)
+    return GetResult(*res, breached=breached)
 
 
 def _mapped_mig_get(mesh: Mesh, axis: str, n_shards: int, capacity: int,
@@ -1730,15 +1765,13 @@ def _mapped_mig_get(mesh: Mesh, axis: str, n_shards: int, capacity: int,
         neighborhood=neighborhood, val_words=val_words)
 
     def body(ok, ov, nk, nv, wm, queries, live):
-        found, v, okk = path(ok, ov, nk, nv, wm, queries, live)
-        deferred = jnp.sum(~live, dtype=jnp.int32).reshape(1)
-        dropped = (jnp.sum(live, dtype=jnp.int32)
-                   - jnp.sum(okk, dtype=jnp.int32)).reshape(1)
-        return found, v, okk, dropped, deferred
+        found, v, okk, breached = path(ok, ov, nk, nv, wm, queries, live)
+        lost = jnp.sum(breached, dtype=jnp.int32).reshape(1)
+        return (found, v, okk, *_get_counts(live, okk, lost), lost)
 
     spec = P(axis)
     fn = jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(spec,) * 7, out_specs=(spec,) * 5,
+        body, mesh=mesh, in_specs=(spec,) * 7, out_specs=(spec,) * 6,
         check_vma=False))
     return _mapped_cache_put(key, fn)
 

@@ -212,11 +212,12 @@ class ShardedKVService(_HostDriverLifecycle):
             seq, kind, arrays = pending
             counts = [np.asarray(a) for a in arrays]
             if kind == "get":
-                steps, = counts                  # (S, S * capacity)
+                steps, words = counts    # (S, S * capacity), (S,)
                 trips = steps.max(axis=1)
                 obs.mark("kv.counters", seq=seq, kind=kind,
                          trips=int(trips.max()), steps=int(steps.sum()),
-                         lanes=int((trips * steps.shape[1]).sum()))
+                         lanes=int((trips * steps.shape[1]).sum()),
+                         image_words=int(words.max()))
             else:
                 scanned, escalated = counts      # (S,) each
                 obs.mark("kv.counters", seq=seq, kind=kind,
@@ -244,7 +245,7 @@ class ShardedKVService(_HostDriverLifecycle):
         seq = self._begin_call()
         with obs.span("kv.get_many", seq=seq, width=int(np.size(queries))):
             res = self._get_many(queries, now, **kwargs)
-        self._hold_counters(seq, "get", res.vm_steps)
+        self._hold_counters(seq, "get", res.vm_steps, res.image_words)
         return res
 
     def _get_many(self, queries, now=None, **kwargs) -> "kv_store.GetResult":

@@ -309,32 +309,42 @@ def local_chain_group(group_fn: Callable, carry, payload: jnp.ndarray,
     return resp.reshape(-1, resp.shape[-1])[:rows], carry
 
 
-def triggered_chain_engine(engine, state, recv_wq: int, resp_region: int,
-                           resp_words: int, payload: jnp.ndarray,
-                           dest: jnp.ndarray, n_shards: int, capacity: int,
-                           axis_name: str,
+def triggered_chain_engine(engine, state, segment, words, recv_wq: int,
+                           resp_region: int, resp_words: int,
+                           payload: jnp.ndarray, dest: jnp.ndarray,
+                           n_shards: int, capacity: int, axis_name: str,
                            live: Optional[jnp.ndarray] = None,
-                           max_steps: int = 256
-                           ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                           max_steps: int = 256):
     """The RedN pattern: SEND triggers a pre-posted chain VM program.
 
     Every arriving request (one slot of the owner's (n_shards, capacity)
     receive window) is delivered as a client SEND to ``recv_wq`` of an
-    independent chain-VM context sharing the owner's memory image
-    (``state``), and all contexts execute in one vmapped
-    ``ChainEngine.run_many`` call — the chain, not the host, computes the
-    answer.  The caller pays exactly one dispatch/combine pair (1 RTT)
-    regardless of the chain's complexity — the paper's core performance
-    claim.  Returns (responses (B, resp_words), ok (B,), steps
-    (n_shards * capacity,)): each response is the context's
-    ``resp_region`` snapshot after its chain quiesced, and ``steps`` the
-    WRs each context of the owner's receive window executed.
+    independent chain-VM context, and all contexts execute in one vmapped
+    ``ChainEngine.run_many_segmented`` call — the chain, not the host,
+    computes the answer.  The owner's image is split at the program's
+    read-only ``segment``: each context carries its own copy of the
+    private image ``state`` (``resp_region`` is the response's address
+    there), and all of them read the segment's ``words`` from one array.
+    The caller pays exactly one dispatch/combine pair (1 RTT) regardless
+    of the chain's complexity — the paper's core performance claim.
+
+    Returns (responses (B, resp_words), ok (B,), steps (n_shards *
+    capacity,), breached (B,)): each response is the context's
+    ``resp_region`` snapshot after its chain quiesced, ``steps`` the WRs
+    each context of the owner's receive window executed, and ``breached``
+    marks a request whose context halted on a store into the segment —
+    not an answer, so ``ok`` is False there.
     """
     recv, pos, ok = dispatch(payload, dest, n_shards, capacity, axis_name,
                              live)
     flat = recv.reshape(-1, recv.shape[-1])
     with obs.scope("kv.get.vm"):
-        out = engine.run_many(state, recv_wq, flat, max_steps)
+        out, breach = engine.run_many_segmented(state, segment, words,
+                                                recv_wq, flat, max_steps)
     resp = out.mem[:, resp_region:resp_region + resp_words]
-    resp = resp.reshape(n_shards, capacity, resp_words)
-    return combine(resp, dest, pos, ok, axis_name), ok, out.steps
+    # the breach flag travels back with the response it spoils
+    resp = jnp.concatenate([resp, breach[:, None].astype(resp.dtype)], 1)
+    back = combine(resp.reshape(n_shards, capacity, resp_words + 1), dest,
+                   pos, ok, axis_name)
+    breached = back[:, -1] > 0
+    return back[:, :-1], ok & ~breached, out.steps, breached

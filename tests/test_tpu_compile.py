@@ -124,3 +124,17 @@ def test_get_at_smoke_size_fits_v5e_hbm(v5e, smoke):
     total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
              + mem.output_size_in_bytes)
     assert total < V5E_HBM_BYTES, total
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_get_reads_one_shared_shard_at_bench_size(v5e, chips):
+    """The GET body at the benchmark's shard (2^20 buckets, GET capacity
+    64, V=7), on one chip and on each of four: its contexts read the
+    table and value rows from one shared read-only segment, so the body
+    declares under 1 GB of temporaries (11.83 GB while every context
+    carried its own copy of the shard)."""
+    mesh = Mesh(np.array(v5e.devices[:chips]), ("kv",))
+    fn = store._mapped_get(mesh, "kv", "redn", chips, 64, 8, 7)
+    mem = fn.lower(*_get_args(mesh, 1 << 20, 64, 7)).compile(
+    ).memory_analysis()
+    assert 0 < mem.temp_size_in_bytes < 10**9, mem.temp_size_in_bytes
