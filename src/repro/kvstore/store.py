@@ -175,7 +175,9 @@ class GetResult(NamedTuple):
     only) counts the WRs each chain-VM context of the owner's receive
     window executed, padded slots included; the vmapped VM loop runs as
     many trips as an owner's largest count.  ``image_words`` (the same
-    paths) is the words of image each of those contexts carried.
+    paths) is the words of image each of those contexts carried, and
+    ``routed`` the live slots of each owner's receive window: those the
+    transport filled with a nonzero key.
     ``breached`` (chain paths) counts the requests whose chain stored
     into the shard's read-only segment: a fault of the program, never an
     answer, so those rows are ``ok`` False and counted nowhere else."""
@@ -187,6 +189,7 @@ class GetResult(NamedTuple):
     vm_steps: Optional[jnp.ndarray] = None  # (S, S * capacity) int32
     image_words: Optional[jnp.ndarray] = None   # (S,) int32
     breached: Optional[jnp.ndarray] = None      # (S,) int32
+    routed: Optional[jnp.ndarray] = None        # (S,) int32
 
     def __repr__(self):
         # summarized, not the raw-array tuple dump — results show up in
@@ -276,21 +279,21 @@ def _chain_get(srv, keys, vals, exp, q, home, now, live, *, n_shards,
     """One GET stage of the chain server ``srv`` at the owner: the shard
     split at the server's read-only segment, so the window's contexts
     share one copy of the table and value rows.  Returns ``(resp, ok,
-    steps, breached, image_words)``, the last the words each context
-    carried."""
+    steps, breached, routed, image_words)``: ``routed`` the owner's live
+    window slots, ``image_words`` the words each context carried."""
     private, words = srv.device_segment(keys, vals, exp)
     payload = srv.device_payloads(q, home, now)
-    resp, ok, steps, breached = transport.triggered_chain_engine(
+    resp, ok, steps, breached, routed = transport.triggered_chain_engine(
         srv.engine, private, srv.segment, words, srv.recv_wq,
         srv.private_resp_region, srv.resp_words, payload,
         shard_of(q, n_shards), n_shards, capacity, axis, live)
-    return resp, ok, steps, breached, private.mem.shape[-1]
+    return resp, ok, steps, breached, routed, private.mem.shape[-1]
 
 
-def _chain_get_outputs(resp, ok, steps, breached, image_words):
+def _chain_get_outputs(resp, ok, steps, breached, routed, image_words):
     return ((resp[:, 0] > 0)[None], resp[None, :, 1:], ok[None],
             steps[None], jnp.full((1,), image_words, jnp.int32),
-            breached[None])
+            breached[None], routed.reshape(1))
 
 
 def _redn_get_local(keys, vals, queries, live, *, n_shards, capacity, axis,
@@ -587,13 +590,14 @@ def _mapped_get(mesh: Mesh, axis: str, method: str, n_shards: int,
         if not chain:
             return (found, v, ok, *_get_counts(live, ok))
         # the chain path: its contexts' VM steps, image words, breaches
-        steps, image_words, breached = chain
+        # and live window slots
+        steps, image_words, breached, routed = chain
         lost = jnp.sum(breached, dtype=jnp.int32).reshape(1)
         return (found, v, ok, *_get_counts(live, ok, lost), steps,
-                image_words, lost)
+                image_words, lost, routed)
 
     spec = P(axis)
-    n_out = 8 if method == "redn" else 5
+    n_out = 9 if method == "redn" else 5
     fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec, spec),
         out_specs=(spec,) * n_out, check_vma=False))
@@ -615,15 +619,15 @@ def _mapped_get_ttl(mesh: Mesh, axis: str, n_shards: int, capacity: int,
         axis=axis, neighborhood=neighborhood, val_words=val_words)
 
     def body(keys, vals, exp, nows, queries, live):
-        found, v, ok, steps, image_words, breached = path(
+        found, v, ok, steps, image_words, breached, routed = path(
             keys, vals, exp, nows, queries, live)
         lost = jnp.sum(breached, dtype=jnp.int32).reshape(1)
         return (found, v, ok, *_get_counts(live, ok, lost), steps,
-                image_words, lost)
+                image_words, lost, routed)
 
     spec = P(axis)
     fn = jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(spec,) * 6, out_specs=(spec,) * 8,
+        body, mesh=mesh, in_specs=(spec,) * 6, out_specs=(spec,) * 9,
         check_vma=False))
     return _mapped_cache_put(key, fn)
 
@@ -1684,8 +1688,9 @@ def _mig_get_local(ok, ov, nk, nv, wm, queries, live, *, n_shards,
 
     srv_new = programs.build_hopscotch_server(2 * n, val_words,
                                               neighborhood)
-    resp1, ok1, _, bad1, _ = stage(srv_new, nk[0], nv[0], None, q,
-                                   hopscotch.bucket_of(q, 2 * n), None, lv)
+    resp1, ok1, _, bad1, _, _ = stage(srv_new, nk[0], nv[0], None, q,
+                                      hopscotch.bucket_of(q, 2 * n), None,
+                                      lv)
     found1 = resp1[:, 0] > 0
 
     wms = jax.lax.all_gather(wm, axis).reshape(-1)      # (S,) watermarks
@@ -1696,8 +1701,8 @@ def _mig_get_local(ok, ov, nk, nv, wm, queries, live, *, n_shards,
     live2 = lv & ok1 & ~found1 & ~mig_done
 
     srv_old = programs.build_hopscotch_server(n, val_words, neighborhood)
-    resp2, _, _, bad2, _ = stage(srv_old, ok[0], ov[0], None, q, h_old,
-                                 None, live2)
+    resp2, _, _, bad2, _, _ = stage(srv_old, ok[0], ov[0], None, q, h_old,
+                                    None, live2)
     found2 = resp2[:, 0] > 0
 
     found = found1 | found2

@@ -212,12 +212,15 @@ class ShardedKVService(_HostDriverLifecycle):
             seq, kind, arrays = pending
             counts = [np.asarray(a) for a in arrays]
             if kind == "get":
-                steps, words = counts    # (S, S * capacity), (S,)
+                steps, words, routed = counts  # (S, S * capacity), (S,), (S,)
                 trips = steps.max(axis=1)
                 obs.mark("kv.counters", seq=seq, kind=kind,
                          trips=int(trips.max()), steps=int(steps.sum()),
                          lanes=int((trips * steps.shape[1]).sum()),
-                         image_words=int(words.max()))
+                         image_words=int(words.max()),
+                         routed=int(routed.sum()),
+                         routed_max=int(routed.max()),
+                         slots=steps.shape[1], owners=steps.shape[0])
             else:
                 scanned, escalated = counts      # (S,) each
                 obs.mark("kv.counters", seq=seq, kind=kind,
@@ -245,7 +248,8 @@ class ShardedKVService(_HostDriverLifecycle):
         seq = self._begin_call()
         with obs.span("kv.get_many", seq=seq, width=int(np.size(queries))):
             res = self._get_many(queries, now, **kwargs)
-        self._hold_counters(seq, "get", res.vm_steps, res.image_words)
+        self._hold_counters(seq, "get", res.vm_steps, res.image_words,
+                            res.routed)
         return res
 
     def _get_many(self, queries, now=None, **kwargs) -> "kv_store.GetResult":

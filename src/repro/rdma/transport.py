@@ -329,15 +329,18 @@ def triggered_chain_engine(engine, state, segment, words, recv_wq: int,
     of the chain's complexity — the paper's core performance claim.
 
     Returns (responses (B, resp_words), ok (B,), steps (n_shards *
-    capacity,), breached (B,)): each response is the context's
+    capacity,), breached (B,), routed ()): each response is the context's
     ``resp_region`` snapshot after its chain quiesced, ``steps`` the WRs
-    each context of the owner's receive window executed, and ``breached``
+    each context of the owner's receive window executed, ``breached``
     marks a request whose context halted on a store into the segment —
-    not an answer, so ``ok`` is False there.
+    not an answer, so ``ok`` is False there — and ``routed`` counts the
+    owner's live window slots: those whose first payload word, the key
+    in the chain servers' request layout, is nonzero (key 0 is padding).
     """
     recv, pos, ok = dispatch(payload, dest, n_shards, capacity, axis_name,
                              live)
     flat = recv.reshape(-1, recv.shape[-1])
+    routed = jnp.sum(flat[:, 0] != 0, dtype=jnp.int32)
     with obs.scope("kv.get.vm"):
         out, breach = engine.run_many_segmented(state, segment, words,
                                                 recv_wq, flat, max_steps)
@@ -347,4 +350,4 @@ def triggered_chain_engine(engine, state, segment, words, recv_wq: int,
     back = combine(resp.reshape(n_shards, capacity, resp_words + 1), dest,
                    pos, ok, axis_name)
     breached = back[:, -1] > 0
-    return back[:, :-1], ok & ~breached, out.steps, breached
+    return back[:, :-1], ok & ~breached, out.steps, breached, routed

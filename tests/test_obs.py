@@ -63,6 +63,48 @@ def test_get_vm_steps_match_each_context_run_alone(mesh1):
     assert 0 < pad <= steps.max()
 
 
+def test_get_routed_counts_the_live_slots_of_the_window(mesh1):
+    """A call 8 wide with three resident keys, a miss, a key 0 and three
+    rows the admission mask holds back: the owner's window got the four
+    nonzero keys that were dispatched, whatever they answer; the TTL
+    server's path counts the same."""
+    kv = _table([5, 9, 300])
+    dk, dv = kv.device_arrays()
+    q = jnp.asarray([[5, 300, 77, 0, 9, 6, 7, 0]], jnp.int32)
+    live = jnp.asarray([[1, 1, 1, 1, 1, 0, 0, 0]], bool)
+    res = store.sharded_get(mesh1, "kv", dk, dv, q, live=live)
+    assert np.asarray(res.routed).tolist() == [4]
+    assert np.asarray(res.routed).dtype == np.int32
+    exp = jnp.full(dk.shape, programs.NO_TTL, jnp.int32)
+    ttl = store.sharded_get(mesh1, "kv", dk, dv, q, live=live, exp=exp,
+                            now=1)
+    assert np.asarray(ttl.routed).tolist() == [4]
+    assert np.asarray(store.sharded_get(
+        mesh1, "kv", dk, dv, jnp.zeros((1, 8), jnp.int32)).routed
+    ).tolist() == [0]
+
+
+def test_traced_get_counters_carry_the_routed_slots(monkeypatch, tmp_path):
+    """While tracing, a GET call's ``kv.counters`` span reads its live
+    window slots over owners (``routed``), the busiest owner's
+    (``routed_max``), and the slots and owners of the receive windows."""
+    svc = failure.ShardedKVService.start([(5, [1, 2]), (9, [3, 4])],
+                                         n_shards=1, buckets_per_shard=NB,
+                                         val_words=V)
+    marks = []
+    monkeypatch.setattr(obs, "mark", lambda name, **a: marks.append(a))
+    q = np.asarray([[5, 6, 9, 0]], np.int32)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        svc.get_many(q)
+        svc.get_many(q)
+    finally:
+        jax.profiler.stop_trace()
+    (got,) = [m for m in marks if m.get("kind") == "get"]
+    assert {k: got[k] for k in ("routed", "routed_max", "slots", "owners")
+            } == {"routed": 3, "routed_max": 3, "slots": 4, "owners": 1}
+
+
 def test_set_counts_scanned_and_escalated_rows(mesh1):
     """An update, a displacement-requiring insert, a fresh insert and a
     duplicate of the displaced key, in a call 8 wide: the writer scan runs
