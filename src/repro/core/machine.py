@@ -220,12 +220,14 @@ def _maybe_store(mem, addr, value):
 
 
 def _recv_scatter(mem, a, n, payload):
-    """RECV: mem[table[i]] = payload[i] for i < n, table at ``a + 1``."""
+    """RECV: mem[table[i]] = payload[i] for i < n, table at ``a + 1``.
+    The loop runs ``n`` trips, so the steps that are no RECV (``n`` 0),
+    which reach it too, run none."""
     def scatter(i, m):
         sd = jnp.maximum(m[a + 1 + i], 0)
-        return m.at[sd].set(jnp.where(i < n, payload[i], m[sd]))
+        return m.at[sd].set(payload[i])
 
-    return lax.fori_loop(0, isa.MAX_SCATTER, scatter, mem)
+    return lax.fori_loop(0, n, scatter, mem)
 
 
 class _WholeImage:

@@ -748,14 +748,27 @@ class HopscotchShardWriter:
     ``[SET_NEEDS_DISPLACEMENT, 0]`` — the cue for the displacer-chain
     escalation stage (:class:`HopscotchShardDisplacer`).
 
-    Contexts are ephemeral: the authoritative shard arrays live outside
-    the image, :meth:`device_state` scatters them in per run, and
-    :meth:`commit` folds a finished context's effects (status word, bucket
-    address, and the value row *the chain wrote*) back into the arrays.
     Requests against one shard are serialized
     (``transport.triggered_chain_stateful`` / :meth:`set_many` scan), as
     the NIC serializes atomics against local memory — so a batch behaves
-    exactly like the host oracle applied in order.
+    exactly like the host oracle applied in order.  Two steps serve a
+    request:
+
+    * the **carried image** (:meth:`run_carried`, the steady-state sharded
+      path): the scan carries the writer's whole image, built once per
+      call by :meth:`device_image` without a scatter.  Table and value
+      rows are one contiguous range (:attr:`rows`); each request resets
+      only the words outside it, so the rows a chain wrote stay in the
+      image for the next request, and :meth:`image_arrays` reads the
+      shard arrays back after the scan.  The chain stores into the rows
+      only on a path that answers ``SET_UPDATED``/``SET_INSERTED``, so
+      this equals the oracle below bit for bit.
+    * the **whole-image oracle** (:meth:`run_one`, :meth:`set_many`, the
+      faulted, racing-writer and migrating paths): the authoritative
+      arrays live outside the image, :meth:`device_state` scatters them
+      in per request, and :meth:`commit` folds a finished context's
+      effects (status word, bucket address, and the value row *the chain
+      wrote*) back into the arrays.
     """
     prog: Program
     spec: machine.MachineSpec
@@ -875,14 +888,60 @@ class HopscotchShardWriter:
                 payload: jnp.ndarray, max_steps: int = 512):
         """Serve one assembled request against the shard arrays: build the
         image, deliver the SEND, run the chain to quiescence, commit.
-        The single step both :meth:`set_many` and the sharded path's scan
-        (``transport.triggered_chain_stateful``) are built from.
+        The step :meth:`set_many` and the whole-image sharded paths
+        (faulted, racing writers, migrating) are built from.
         Returns ``(status, new_keys, new_vals)``.
         """
         st = machine.deliver(self.device_state(keys, vals), self.recv_wq,
                              payload)
         out = self.engine.run(st, max_steps)
         return self.commit(out.mem, payload, keys, vals)
+
+    @property
+    def rows(self) -> machine.Segment:
+        """The table rows ``[key, pad, val_ptr]`` and the value rows: one
+        contiguous range, since the program allocates downward."""
+        return machine.Segment(self.table_base,
+                               self.values_base + self.n_buckets * self.val_len)
+
+    def device_image(self, keys: jnp.ndarray,
+                     vals: jnp.ndarray) -> jnp.ndarray:
+        """``device_state(keys, vals).mem`` built without a scatter: the
+        table rows by ``stack``, the value rows by ``reshape``, both
+        written into the static image with one ``dynamic_update_slice``."""
+        keys = keys.astype(jnp.int32)
+        val_ptr = self.values_base + self.val_len * jnp.arange(
+            self.n_buckets, dtype=jnp.int32)
+        table = jnp.stack([keys, jnp.zeros_like(keys), val_ptr], axis=1)
+        words = jnp.concatenate([table.reshape(-1),
+                                 vals.astype(jnp.int32).reshape(-1)])
+        return jax.lax.dynamic_update_slice(self.state0.mem, words,
+                                            (self.table_base,))
+
+    def image_arrays(self, mem: jnp.ndarray):
+        """The shard arrays ``(keys, vals)`` of a carried image, by a
+        strided slice and a reshape."""
+        lo, hi = self.rows
+        # lax.slice: a strided jnp index would lower to a gather
+        keys = jax.lax.slice(mem, (lo,), (self.values_base,), (BUCKET_WORDS,))
+        vals = mem[self.values_base:hi].reshape(self.n_buckets, self.val_len)
+        return keys, vals
+
+    def run_carried(self, mem: jnp.ndarray, payload: jnp.ndarray,
+                    max_steps: int = 512):
+        """:meth:`run_one` against a carried image: reset every word
+        outside :attr:`rows` and every other field of the state to
+        ``state0``, deliver the SEND, run the chain to quiescence.  The
+        rows the chain wrote stay in the returned image; nothing is
+        committed.  Returns ``(status, mem)``, status 0 for key 0."""
+        lo, hi = self.rows
+        mem = mem.at[:lo].set(self.state0.mem[:lo])
+        mem = mem.at[hi:].set(self.state0.mem[hi:])
+        st = machine.deliver(self.state0._replace(mem=mem), self.recv_wq,
+                             payload)
+        out = self.engine.run(st, max_steps)
+        status = out.mem[self.resp_region]
+        return jnp.where(payload[0] == EMPTY_KEY, 0, status), out.mem
 
     def run_one_faulted(self, keys: jnp.ndarray, vals: jnp.ndarray,
                         payload: jnp.ndarray, max_steps: int,
@@ -1406,6 +1465,13 @@ class HopscotchShardDisplacer(HopscotchShardWriter):
     """
     max_search: int = 0
     max_moves: int = 0
+
+    def run_carried(self, mem, payload, max_steps: int = 512):
+        """Refused: a failed bubble leaves moved rows in the image and the
+        frame is unwrapped, so each displacer request runs against an
+        image rebuilt from the arrays (:meth:`run_one`)."""
+        raise NotImplementedError(
+            "the displacer runs only against a rebuilt image (run_one)")
 
     def device_state(self, keys: jnp.ndarray,
                      vals: jnp.ndarray) -> machine.VMState:

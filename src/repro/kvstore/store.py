@@ -668,8 +668,9 @@ def _guarded_step(run_one, budget, run_one_faulted=None):
     steady-state serving from paying a quiesce-run per dead slot.
 
     Generic over the carry arity: ``run_one(*carry, payload, budget)
-    -> (status, *carry)`` — the writer/displacer thread ``(keys,
-    vals)``, the resize migrator threads both frames.
+    -> (status, *carry)`` — the steady-state writer threads its image,
+    the displacer and the whole-image writer paths ``(keys, vals)``, the
+    resize migrator both frames.
 
     With ``run_one_faulted`` the returned step consumes ``(payload,
     fault_row)`` tuples (the transport's ``faults=`` wire format) and
@@ -757,7 +758,10 @@ class SetResult(NamedTuple):
     drop), so callers never observe it.  ``applied`` acks the rows the
     device arrays absorbed.  ``scanned`` and ``escalated`` (steady-state
     paths only) count the live rows each owner's writer scan ran and the
-    rows it re-ran through the displacer."""
+    rows it re-ran through the displacer; ``image_builds`` the whole
+    chain-VM images the owner built in the call (1 + ``escalated`` where
+    the writer scan carries its image, one per row or lap where each
+    builds its own)."""
     status: jnp.ndarray     # (S, B) int32 — the path taken per request
     applied: jnp.ndarray    # (S, B) bool — committed to the device arrays
     ok: jnp.ndarray         # (S, B) bool — response authoritative
@@ -765,6 +769,7 @@ class SetResult(NamedTuple):
     deferred: jnp.ndarray   # (S,) int32
     scanned: Optional[jnp.ndarray] = None    # (S,) int32 at the owner
     escalated: Optional[jnp.ndarray] = None  # (S,) int32 at the owner
+    image_builds: Optional[jnp.ndarray] = None  # (S,) int32 at the owner
 
     def __repr__(self):
         return _mutation_repr("SetResult", self)
@@ -852,11 +857,15 @@ def _writer_set_local(keys, vals, qk, qv, live, *, n_shards, capacity, axis,
     """Owner-side SET serving: the pre-posted writer chain CAS-claims /
     updates buckets; requests against one shard are serialized so each
     chain observes its predecessors' writes (no host lookup anywhere).
+    The scan carries the writer's image, built once per call
+    (:meth:`~repro.core.programs.HopscotchShardWriter.run_carried`).
     Rows the fast writer answers ``SET_NEEDS_DISPLACEMENT`` escalate to
     the displacer chain (:func:`_displace_stage`).
 
-    Returns ``(status, ok, keys, vals, scanned, escalated)``: the last
-    two count the live rows this owner's writer and displacer scans ran.
+    Returns ``(status, ok, keys, vals, scanned, escalated,
+    image_builds)``: the live rows this owner's writer and displacer
+    scans ran, and the whole images it built (one, plus one per
+    displacer row).
     """
     q = qk.reshape(-1)
     dest = shard_of(q, n_shards)
@@ -866,12 +875,14 @@ def _writer_set_local(keys, vals, qk, qv, live, *, n_shards, capacity, axis,
                                              neighborhood)
     payload = writer.device_payloads(q, hopscotch.bucket_of(q, n_buckets),
                                      qv.reshape(-1, val_words))
+    image = writer.device_image(keys[0], vals[0])
 
     with obs.scope("kv.set.scan"):
-        resp, ok, ((nk, nv), scanned) = transport.triggered_chain_stateful(
-            _counted(_guarded_step(writer.run_one, max_steps)),
-            ((keys[0], vals[0]), jnp.int32(0)), payload, dest, n_shards,
-            capacity, axis, 1, lv)
+        resp, ok, ((image,), scanned) = transport.triggered_chain_stateful(
+            _counted(_guarded_step(writer.run_carried, max_steps)),
+            ((image,), jnp.int32(0)), payload, dest, n_shards, capacity,
+            axis, 1, lv)
+    nk, nv = writer.image_arrays(image)
     status = resp[:, 0]
     live2 = ok & (status == programs.SET_NEEDS_DISPLACEMENT)
     status, nk, nv, escalated = _displace_stage(
@@ -880,7 +891,7 @@ def _writer_set_local(keys, vals, qk, qv, live, *, n_shards, capacity, axis,
         val_words=val_words, max_steps=max_steps, max_search=max_search,
         max_moves=max_moves)
     return (status[None], ok[None], nk[None], nv[None], scanned.reshape(1),
-            escalated.reshape(1))
+            escalated.reshape(1), (1 + escalated).reshape(1))
 
 
 def _writer_set_local_faulted(keys, vals, qk, qv, live, frows, *, n_shards,
@@ -888,8 +899,12 @@ def _writer_set_local_faulted(keys, vals, qk, qv, live, frows, *, n_shards,
                               max_steps, max_search, max_moves):
     """Owner-side SET serving under injected faults — the recovery
     drill's first act.  Same wire pattern as :func:`_writer_set_local`,
-    with two deliberate differences:
+    with three deliberate differences:
 
+    * each row rebuilds the whole image and commits it
+      (``run_one_faulted``; ``commit_torn`` for an armed row), where the
+      plain path carries one image: with no fault armed this is the
+      per-row oracle that path is tested against;
     * each request's packed fault row rides its payload through
       dispatch (``transport.triggered_chain_stateful(faults=...)``) and
       arms the writer chain for exactly that request
@@ -928,7 +943,7 @@ def _writer_set_local_faulted(keys, vals, qk, qv, live, frows, *, n_shards,
         val_words=val_words, max_steps=max_steps, max_search=max_search,
         max_moves=max_moves)
     return (status[None], ok[None], nk[None], nv[None], scanned.reshape(1),
-            escalated.reshape(1))
+            escalated.reshape(1), (scanned + escalated).reshape(1))
 
 
 def _mw_set_local(keys, vals, qk, qv, live, *, n_shards, capacity, axis,
@@ -979,8 +994,9 @@ def _mw_set_local(keys, vals, qk, qv, live, *, n_shards, capacity, axis,
         capacity=capacity, axis=axis, neighborhood=neighborhood,
         val_words=val_words, max_steps=max_steps, max_search=max_search,
         max_moves=max_moves)
+    laps = -(-n_shards * capacity // n_writers)     # each lap builds one
     return (status[None], ok[None], nk[None], nv[None], scanned.reshape(1),
-            escalated.reshape(1))
+            escalated.reshape(1), (laps + escalated).reshape(1))
 
 
 def relocate_exp(old_keys: jnp.ndarray, old_exp: jnp.ndarray,
@@ -1141,12 +1157,13 @@ def _set_table(mesh: Mesh, axis: str, keys: jnp.ndarray, vals: jnp.ndarray,
     args = (keys, vals, set_keys, set_vals, live)
     if faults is not None:
         args += (faults.as_rows(),)
-    status, ok, dropped, deferred, nk, nv, scanned, escalated = mapped(*args)
+    (status, ok, dropped, deferred, nk, nv, scanned, escalated,
+     image_builds) = mapped(*args)
     applied = ok & ((status == programs.SET_UPDATED)
                     | (status == programs.SET_INSERTED)
                     | (status == programs.SET_DISPLACED))
     result = SetResult(status, applied, ok, dropped, deferred, scanned,
-                       escalated)
+                       escalated, image_builds)
     if exp is not None:
         # deadline follow-up is commit-layer state: the writer/displacer
         # chains may have relocated keys, so re-home the column by key
@@ -1194,17 +1211,17 @@ def _mapped_set(mesh: Mesh, axis: str, n_shards: int, capacity: int,
         # unused (key-0) slots are inert: no dispatch slot, no counter
         real = qk != hopscotch.EMPTY
         live = live & real
-        status, ok, nk, nv, scanned, escalated = path(keys, vals, qk, qv,
-                                                      live, *frows)
+        status, ok, nk, nv, *counts = path(keys, vals, qk, qv, live,
+                                           *frows)
         deferred = jnp.sum(~live & real, dtype=jnp.int32).reshape(1)
         dropped = (jnp.sum(live, dtype=jnp.int32)
                    - jnp.sum(ok, dtype=jnp.int32)).reshape(1)
-        return status, ok, dropped, deferred, nk, nv, scanned, escalated
+        return (status, ok, dropped, deferred, nk, nv, *counts)
 
     spec = P(axis)
     fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(spec,) * (6 if faulted else 5),
-        out_specs=(spec,) * 8, check_vma=False))
+        out_specs=(spec,) * 9, check_vma=False))
     return _mapped_cache_put(key, fn)
 
 

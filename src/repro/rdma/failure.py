@@ -222,11 +222,12 @@ class ShardedKVService(_HostDriverLifecycle):
                          routed_max=int(routed.max()),
                          slots=steps.shape[1], owners=steps.shape[0])
             else:
-                scanned, escalated = counts      # (S,) each
+                scanned, escalated, builds = counts      # (S,) each
                 obs.mark("kv.counters", seq=seq, kind=kind,
                          scanned=int(scanned.sum()),
                          scanned_max=int(scanned.max()),
-                         escalated=int(escalated.sum()))
+                         escalated=int(escalated.sum()),
+                         image_builds=int(builds.sum()))
         self._seq += 1
         return self._seq
 
@@ -322,7 +323,8 @@ class ShardedKVService(_HostDriverLifecycle):
         seq = self._begin_call()
         with obs.span("kv.set_many", seq=seq, width=int(np.size(set_keys))):
             res = self._set_many(set_keys, set_vals, deadlines, **kwargs)
-        self._hold_counters(seq, "set", res.scanned, res.escalated)
+        self._hold_counters(seq, "set", res.scanned, res.escalated,
+                            res.image_builds)
         return res
 
     def _set_many(self, set_keys, set_vals, deadlines=None,

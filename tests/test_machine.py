@@ -1,4 +1,5 @@
 """Unit tests for the RedN chain VM: verbs, ordering, self-modification."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -139,6 +140,28 @@ def test_send_recv_scatter():
     state = machine.deliver(state, 0, [123, 456])
     out = machine.run(spec, state, 64)
     assert int(out.mem[a]) == 123 and int(out.mem[b]) == 456
+
+
+def test_recv_writes_only_the_words_its_table_lists():
+    """A RECV writes the words its scatter table lists and no other,
+    whatever the payload carries past them; batched, two contexts whose
+    RECVs list two and four words each get their own count."""
+    p = assembler.Program(512)
+    words = [p.word(-1) for _ in range(4)]
+    two = p.add_wq(2)
+    two.recv(scatter_table=p.scatter_table(words[:2]))
+    four = p.add_wq(2)
+    four.recv(scatter_table=p.scatter_table(words))
+    spec, state = p.finalize()
+    payload = [11, 22, 33, 44, 55]
+    st2 = machine.deliver(state, two.index, payload)
+    st4 = machine.deliver(state, four.index, payload)
+    out = machine.run(spec, st2, 64)
+    assert [int(out.mem[w]) for w in words] == [11, 22, -1, -1]
+    both = machine.run_batch(
+        spec, jax.tree.map(lambda a, b: jnp.stack([a, b]), st2, st4), 64)
+    assert [[int(m[w]) for w in words] for m in both.mem] == [
+        [11, 22, -1, -1], [11, 22, 33, 44]]
 
 
 def test_send_to_peer_qp():

@@ -13,6 +13,7 @@ test file.
 """
 import importlib.util
 import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +21,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core import faults as faults_mod
 from repro.core import isa, machine, programs
 from repro.kernels.chain_vm import ops as chain_ops
 from repro.kvstore import hopscotch, store
@@ -138,3 +140,103 @@ def test_get_reads_one_shared_shard_at_bench_size(v5e, chips):
     mem = fn.lower(*_get_args(mesh, 1 << 20, 64, 7)).compile(
     ).memory_analysis()
     assert 0 < mem.temp_size_in_bytes < 10**9, mem.temp_size_in_bytes
+
+
+def _hlo_computations(text):
+    """A compiled module's HLO text as ``{computation: [instruction]}``."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head and not line.startswith(" "):
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line.strip())
+    return comps
+
+
+_CALLS = re.compile(r"(?:condition|body|calls|to_apply|true_computation|"
+                    r"false_computation)=%([\w.\-]+)|"
+                    r"branch_computations=\{([^}]*)\}")
+
+
+def _reachable(comps, root):
+    seen, todo = set(), [root]
+    while todo:
+        c = todo.pop()
+        if c in seen:
+            continue
+        seen.add(c)
+        for line in comps.get(c, ()):
+            for m in _CALLS.finditer(line):
+                todo += ([m.group(1)] if m.group(1) else
+                         [x.strip().lstrip("%") for x in m.group(2).split(",")])
+    return seen
+
+
+def _elements(shape):
+    dims = re.search(r"\[([\d,]*)\]", shape).group(1)
+    return int(np.prod([int(d) for d in dims.split(",") if d]))
+
+
+def _scatters_in_writer_loops(text, image_words):
+    """The update counts of every scatter reachable from the body of a
+    loop that carries the writer's image (``s32[image_words]``) or runs
+    one that does: the writer scan and the chain-VM loops inside it."""
+    comps = _hlo_computations(text)
+    image = f"s32[{image_words}]"
+    loops = []                      # (computation reached, carries image)
+    for lines in comps.values():
+        for line in lines:
+            if " while(" in line:
+                carry = line.split(" while(")[0]
+                body = re.search(r"body=%([\w.\-]+)", line).group(1)
+                loops.append((_reachable(comps, body), image in carry))
+    inner = set().union(*(r for r, carries in loops if carries))
+    outer = [r for r, carries in loops
+             if carries or any(r2 <= r for r2, c2 in loops if c2)]
+    found = []
+    for c in set().union(inner, *outer):
+        shapes = {}
+        for line in comps[c]:
+            m = re.match(r"(?:ROOT )?%([\w.\-]+) = (\S+) (\S+?)\((.*?)\)",
+                         line)
+            if not m:
+                continue
+            shapes[m.group(1)] = m.group(2)
+            if m.group(3) == "scatter":
+                ops = [o.strip().lstrip("%") for o in m.group(4).split(",")]
+                found.append(_elements(shapes[ops[2]]))
+    return found
+
+
+@pytest.mark.parametrize("buckets,faulted", [(1 << 20, False),
+                                             (1 << 12, True)])
+def test_set_writer_scan_rebuilds_no_image_per_row(v5e, buckets, faulted):
+    """The steady-state SET body at the benchmark's shard (2^20 buckets,
+    V=7) and width 16: the writer scan carries the writer's image, so no
+    scatter over the shard's rows runs inside its loop.  The faulted body,
+    which still rebuilds the image for every row, shows what the search
+    finds where a loop does (scatters of n_buckets keys and
+    n_buckets x V value words)."""
+    mesh = Mesh(np.array(v5e.devices[:1]), ("kv",))
+    val_words, width = 7, 16
+    fn = store._mapped_set(mesh, "kv", 1, width, 8, val_words, 512,
+                           hopscotch.DEFAULT_MAX_SEARCH,
+                           hopscotch.DEFAULT_MAX_MOVES, faulted=faulted)
+    shapes = [((1, buckets), jnp.int32),
+              ((1, buckets, val_words), jnp.int32),
+              ((1, width), jnp.int32), ((1, width, val_words), jnp.int32),
+              ((1, width), jnp.bool_)]
+    if faulted:
+        shapes.append(((1, width, faults_mod.FIELDS), jnp.int32))
+    text = fn.lower(*_shapes(mesh, *shapes)).compile().as_text()
+    writer = programs.build_hopscotch_writer(buckets, val_words, 8)
+    found = _scatters_in_writer_loops(
+        text, writer.spec.mem_words + machine.GUARD_WORDS)
+    big = sorted(n for n in found if n >= buckets)
+    if faulted:
+        assert big == [buckets, buckets * val_words], found
+    else:
+        assert big == [], found
